@@ -20,9 +20,13 @@ class Timer:
 
 
 def run_subprocess_devices(code: str, n_devices: int, timeout=560) -> str:
-    """Run a python snippet with N host-emulated devices; returns stdout."""
+    """Run a python snippet with N host-emulated devices; returns stdout.
+
+    The child is pinned to the CPU backend: host-emulated devices are CPU
+    devices, and on a TPU host an unpinned child would claim the chip."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.abspath(SRC)
     res = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=timeout)

@@ -76,9 +76,15 @@ def run(tiny: bool = False):
     from repro.kernels import ops
     from repro.launch import analysis as A
 
+    iters = 2 if tiny else 5
+    # ring schedules on an 8-way host mesh, in a child started BEFORE this
+    # process first touches its JAX backend (one process per chip)
+    b_, t_, d_, m_ = (2, 32, 128, 128) if tiny else (4, 256, 512, 512)
+    ring_out = run_subprocess_devices(
+        RING_CODE.format(b=b_, t=t_, d=d_, m=m_, iters=iters,
+                         with_pallas=not tiny), 8)
     backend = jax.default_backend()
     mode = "compiled" if backend == "tpu" else "cpu-interpret"
-    iters = 2 if tiny else 5
     rows = []
 
     # --- single-GEMM A/B: bias + GELU epilogue ------------------------
@@ -119,12 +125,8 @@ def run(tiny: bool = False):
                          int(t * 1e6),
                          f"gflops={flops / t / 1e9:.1f}|mode={mode}"))
 
-    # --- ring schedules on an 8-way host mesh (subprocess) ------------
-    b_, t_, d_, m_ = (2, 32, 128, 128) if tiny else (4, 256, 512, 512)
-    out = run_subprocess_devices(
-        RING_CODE.format(b=b_, t=t_, d=d_, m=m_, iters=iters,
-                         with_pallas=not tiny), 8)
-    for line in out.splitlines():
+    # --- ring schedules on an 8-way host mesh (child above) ------------
+    for line in ring_out.splitlines():
         if line.startswith("RING"):
             _, impl, kern, us = line.split()
             tag = f"kf/ring/{impl}" + ("" if kern == "xla" else f"/{kern}")

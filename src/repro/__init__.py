@@ -1,9 +1,1 @@
-"""Jigsaw reproduction package.
-
-Importing the package installs the JAX version-compatibility shims
-(``repro.compat``) so modules written against the modern jax API run on
-the pinned jax of this environment.
-"""
-from repro import compat  # noqa: F401  (side effect: compat.install())
-
-__all__ = ["compat"]
+"""Jigsaw reproduction package."""

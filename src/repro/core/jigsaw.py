@@ -65,9 +65,9 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
-from repro.compat import get_abstract_mesh, shard_map
 from repro.core.sharding import ShardingRules, constrain
 
 Impl1D = ("ring", "ring_chunked", "ring_fused", "rs", "gspmd", "allreduce")

@@ -50,7 +50,7 @@ def _kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, n_k: int,
     def _finish():
         out = acc_ref[...]
         if b_ref is not None:
-            out = out + b_ref[...].astype(jnp.float32)[None, :]
+            out = out + b_ref[...].astype(jnp.float32)      # (1, bn) row
         if epilogue == "gelu":
             out = jax.nn.gelu(out)
         elif epilogue == "silu":
@@ -94,8 +94,10 @@ def block_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
     ]
     args = [x, w]
     if b is not None:
-        in_specs.append(pl.BlockSpec((block_n,), lambda i, j, kk: (j,)))
-        args.append(b)
+        # the bias rides as a (1, N) row: Mosaic refuses a 1-D block whose
+        # tiling differs from XLA's layout of the [N] operand
+        in_specs.append(pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)))
+        args.append(b.reshape(1, n))
         kernel = functools.partial(_kernel, n_k=n_k, epilogue=epilogue)
     else:
         kernel = functools.partial(

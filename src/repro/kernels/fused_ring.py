@@ -63,6 +63,7 @@ except ImportError:  # pragma: no cover - exotic builds only
 
 from repro.kernels import ops
 from repro.kernels.block_matmul import sublane
+from repro.telemetry.spans import get_tracer
 
 # Per-core VMEM we allow the fused kernel to pin (16 MB on v4/v5 cores,
 # minus headroom for the pipeline's own double buffers).
@@ -115,8 +116,8 @@ def _select_path(rows: int, d_local: int, m: int, p: int, x_dtype,
     the guard logic itself is testable on CPU."""
     backend = backend or jax.default_backend()
     if backend != "tpu" or pltpu is None:
-        return "fallback"
-    if mesh_axes is None or axis_name not in mesh_axes:
+        path = "fallback"
+    elif mesh_axes is None or axis_name not in mesh_axes:
         # Neighbour addressing needs every mesh axis's coordinate; a
         # partially-manual mesh (or a caller that didn't thread the axis
         # names) cannot build them.
@@ -125,16 +126,21 @@ def _select_path(rows: int, d_local: int, m: int, p: int, x_dtype,
                    "fused_ring: cannot address ring neighbours (mesh axes "
                    f"unavailable for ring {axis_name!r}); falling back to "
                    "the chunk-granular ring_chunked schedule")
-        return "fallback"
-    if not fits_vmem(rows, d_local, m, p, x_dtype, accum_dtype,
-                     budget=budget):
+        path = "fallback"
+    elif not fits_vmem(rows, d_local, m, p, x_dtype, accum_dtype,
+                       budget=budget):
         fp = ring_footprint_bytes(rows, d_local, m, p, x_dtype, accum_dtype)
         _warn_once(("vmem", rows, d_local, m, p),
                    f"fused_ring: chunk tiles need ~{fp / 2**20:.1f} MiB "
                    "VMEM > budget; falling back to the chunk-granular "
                    "ring_chunked schedule")
-        return "fallback"
-    return "tpu"
+        path = "fallback"
+    else:
+        path = "tpu"
+    # counted at trace time on the process tracer, so a run can say which
+    # schedule its kernels took
+    get_tracer().counter(f"fused_ring.ring.{path}")
+    return path
 
 
 # --------------------------------------------------------------------------
@@ -179,14 +185,14 @@ def _ring_neighbors(axis_name: str, p: int,
                     mesh_axes: Optional[Sequence[str]]):
     """(succ_id, pred_id, device_id_type) for the ring RDMA.
 
-    With a single-axis mesh the ring position IS the logical device id.
-    With a multi-axis mesh we build full MESH coordinates from the manual
-    axis indices (``mesh_axes`` = mesh.axis_names threaded down from
-    jigsaw_linear), replacing the ring axis's coordinate.
+    Neighbours are addressed by full MESH coordinates built from the
+    manual axis indices (``mesh_axes`` = mesh.axis_names threaded down
+    from jigsaw_linear), replacing the ring axis's coordinate.  A bare
+    ring position is not a device id: the TPU compiler takes LOGICAL ids
+    as scalars only.
     """
     my = jax.lax.axis_index(axis_name)
-    if mesh_axes is None or tuple(mesh_axes) == (axis_name,):
-        return ((my + 1) % p,), ((my - 1) % p,), pltpu.DeviceIdType.LOGICAL
+    mesh_axes = (axis_name,) if mesh_axes is None else mesh_axes
     coords = [jax.lax.axis_index(a) for a in mesh_axes]
     k = list(mesh_axes).index(axis_name)
     succ = list(coords)
@@ -301,7 +307,7 @@ def _ring_fwd_tpu(x: jax.Array, w: jax.Array, axis_name: str, p: int,
                           mesh_axes=mesh_axes, axis_name=axis_name),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, mc), x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), collective_id=0),
     )(my, x2, w)
     return out.reshape(lead + (mc,))
@@ -414,7 +420,7 @@ def _ring_bwd_tpu(x: jax.Array, w: jax.Array, dy: jax.Array,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((rows, d_local), x.dtype),
                    jax.ShapeDtypeStruct(w.shape, w.dtype)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), collective_id=1),
     )(my, x2, w, dy2)
     return dx.reshape(x.shape), dw
@@ -742,7 +748,7 @@ def _cannon_fwd_tpu(w: jax.Array, x: jax.Array, *, dom_axis: str,
                           dom_axis=dom_axis, tp_axis=tp_axis),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_l, ll, c_l), out_dt),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), collective_id=2),
     )(ij, w, x)
     return jnp.moveaxis(out, 0, 1)
@@ -820,13 +826,16 @@ def cannon_path(ll: int, m_l: int, t_l: int, c_l: int, x_dtype,
     """``"tpu"`` when the fused q-hop Cannon kernel can run, else
     ``"step"`` (one pallas_call per Cannon step, rotates via ppermute)."""
     backend = backend or jax.default_backend()
-    if backend != "tpu" or pltpu is None or mesh_axes is None:
-        return "step"
     budget = VMEM_BUDGET_BYTES if budget is None else budget
-    if cannon_footprint_bytes(ll, m_l, t_l, c_l, x_dtype) > budget:
+    if backend != "tpu" or pltpu is None or mesh_axes is None:
+        path = "step"
+    elif cannon_footprint_bytes(ll, m_l, t_l, c_l, x_dtype) > budget:
         _warn_once(("cannon_vmem", ll, m_l, t_l, c_l),
                    "fused_ring: fused Cannon blocks exceed the VMEM "
                    "budget; using the per-step kernel with ppermute "
                    "rotates")
-        return "step"
-    return "tpu"
+        path = "step"
+    else:
+        path = "tpu"
+    get_tracer().counter(f"fused_ring.cannon.{path}")
+    return path

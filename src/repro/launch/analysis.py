@@ -1,6 +1,6 @@
 """Compiled-artifact analysis: roofline terms from the dry-run.
 
-Sources (CPU container, TPU v5e target -- no wall clock available):
+Sources (compiled for a TPU v5e -- no wall clock involved):
   * ``compiled.cost_analysis()``  -> HLO FLOPs + bytes accessed (per-device
     program, post-SPMD-partitioning).
   * ``compiled.as_text()``        -> optimized HLO; we sum operand bytes of
@@ -22,10 +22,41 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # bytes/s
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one TPU kind."""
+    flops_bf16: float         # FLOP/s
+    hbm_bw: float             # bytes/s
+
+
+# Per-chip peaks keyed by jax ``Device.device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM per chip.
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops_bf16=197e12, hbm_bw=819e9),
+}
+V5E = PEAKS["TPU v5 lite"]
+# the analytic Fig. 7 model, the dry-run and the roofline terms below are
+# stated for one v5e chip
+PEAK_FLOPS_BF16 = V5E.flops_bf16
+HBM_BW = V5E.hbm_bw
 ICI_BW = 50e9                 # bytes/s per link (~usable per-chip here)
+
+
+def peaks_for(device) -> Optional[Peaks]:
+    """Peaks of the chip a run is on (a jax ``Device``).
+
+    None off the TPU: a CPU has no entry, so its step records carry no
+    MFU.  A TPU kind missing from ``PEAKS`` raises -- an unknown chip
+    never borrows another chip's peaks."""
+    if device.platform != "tpu":
+        return None
+    try:
+        return PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for TPU kind {device.device_kind!r}; add "
+            f"them to launch/analysis.PEAKS") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

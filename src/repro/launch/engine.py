@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import checkpoint as ckpt
-from repro import compat
 from repro import telemetry
 from repro.configs.registry import get_config
 from repro.core import precision
@@ -147,15 +146,18 @@ class TrainEngine:
         # achieved_tflops (telemetry/accounting.py).
         self.tracer = telemetry.Tracer(enabled=config.telemetry)
         telemetry.set_tracer(self.tracer)
+        device = (self.mesh.devices.flat[0] if self.use_mesh
+                  else jax.devices()[0])
         self.cost_model = telemetry.build_cost_model(
             cfg, n_model=mesh_model, n_data=mesh_data,
-            batch=config.batch, seq_len=config.seq_len)
+            batch=config.batch, seq_len=config.seq_len, device=device)
         self.tracer.set_meta(
             arch=arch, reduced=reduced, mesh_model=mesh_model,
             mesh_data=mesh_data, scheme=cfg.scheme, impl=cfg.impl,
             kernel=cfg.kernel, precision=self.policy.name,
             steps=config.steps, batch=config.batch,
             rollout=config.rollout, zero1=config.zero1,
+            device=self.cost_model.device,
             cost_model=self.cost_model.as_meta())
 
         key = jax.random.PRNGKey(config.seed)
@@ -181,20 +183,22 @@ class TrainEngine:
         # output, so non-zero1 runs no longer come back GSPMD-replicated
         # (which made sharded checkpoints dump all bytes on one rank).
         self._param_shardings = None
+        self._opt_shardings = None
         if self.use_mesh:
             self._param_shardings = self._param_pins()
             self.params = jax.device_put(self.params,
                                          self._param_shardings)
-        self.opt_state = adam.init(self.params, self.adam_cfg)
-        # ZeRO-1 (ROADMAP PR-1 leftover, DESIGN.md §6.5): moments sharded
-        # over the data axis; the step output is pinned to the same
-        # layout so the sharding survives across updates, and GSPMD
-        # allgathers only the fresh params (classic ZeRO-1 schedule).
-        self._opt_shardings = None
-        if config.zero1 and self.use_mesh:
-            self._opt_shardings = self._zero1_shardings()
-            self.opt_state = jax.device_put(self.opt_state,
-                                            self._opt_shardings)
+            # Optimizer state is BORN in its layout -- the parameters'
+            # specs (zero redundancy), plus a data-axis shard of every
+            # moment under ZeRO-1 (DESIGN.md §6.5) -- never whole on one
+            # device; the step output is pinned to the same layout so it
+            # survives across updates.
+            self._opt_shardings = self._opt_pins()
+            self.opt_state = jax.jit(
+                partial(adam.init, cfg=self.adam_cfg),
+                out_shardings=self._opt_shardings)(self.params)
+        else:
+            self.opt_state = adam.init(self.params, self.adam_cfg)
         self.lr_fn = partial(
             sched.warmup_cosine, base_lr=config.lr,
             warmup_steps=max(config.steps // 10, 1),
@@ -257,19 +261,21 @@ class TrainEngine:
         pspecs = S.sanitize_tree(self.params, pspecs, self.mesh)
         return S.to_shardings(pspecs, self.mesh)
 
-    def _zero1_shardings(self):
-        """NamedShardings for the ZeRO-1 optimizer state: moments (and
-        fp32 masters under the bf16 policy) inherit the param specs plus
-        a data-axis shard on their first evenly divisible unsharded dim
-        (launch/specs.opt_specs)."""
+    def _opt_pins(self):
+        """NamedShardings for the optimizer state: moments (and fp32
+        masters under the bf16 policy) inherit the param specs; under
+        ZeRO-1 plus a data-axis shard on their first evenly divisible
+        unsharded dim (launch/specs.opt_specs)."""
         from repro.launch import specs as S
         pspecs = S.param_specs(self.params, self.cfg, self.rules, self.mesh)
         pspecs = S.sanitize_tree(self.params, pspecs, self.mesh)
-        ospecs = S.opt_specs(self.opt_state["mu"], pspecs,
-                             zero1_axis=self.rules.batch_axes[-1],
-                             mesh=self.mesh,
-                             master="master" in self.opt_state)
-        ospecs = S.sanitize_tree(self.opt_state, ospecs, self.mesh)
+        state = jax.eval_shape(partial(adam.init, cfg=self.adam_cfg),
+                               self.params)
+        ospecs = S.opt_specs(state["mu"], pspecs,
+                             zero1_axis=(self.rules.batch_axes[-1]
+                                         if self.config.zero1 else None),
+                             mesh=self.mesh, master="master" in state)
+        ospecs = S.sanitize_tree(state, ospecs, self.mesh)
         return S.to_shardings(ospecs, self.mesh)
 
     def _make_pipeline(self, mode: str, prefetch: int) -> InputPipeline:
@@ -279,7 +285,7 @@ class TrainEngine:
                              prefetch=prefetch, seed=self.config.seed)
 
     def _mesh_ctx(self):
-        return compat.set_mesh(self.mesh) if self.use_mesh \
+        return jax.set_mesh(self.mesh) if self.use_mesh \
             else nullcontext()
 
     # -- single dispatch -------------------------------------------------
@@ -341,6 +347,7 @@ class TrainEngine:
                         tr.step_record(
                             step=i, rollout=r, dur_s=wall,
                             data_wait_s=dw.dur_s,
+                            device=self.cost_model.device,
                             **self.cost_model.metrics(wall, rollout=r))
                         if i % c.log_every == 0 or i == c.steps - 1:
                             m = {k: float(v) for k, v in metrics.items()}

@@ -5,7 +5,8 @@ state; the dry-run sets XLA_FLAGS before anything else imports jax.
 """
 from __future__ import annotations
 
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 AUTO = AxisType.Auto
 

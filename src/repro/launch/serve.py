@@ -26,6 +26,7 @@ from typing import Optional, Sequence
 
 from repro.configs.registry import ARCH_IDS
 from repro.data.weather import WeatherDataConfig, WeatherDataset
+from repro.launch import compile_cache
 from repro.serve.engine import ForecastEngine, ServeConfig
 
 
@@ -107,6 +108,7 @@ def main():
                     help="Chrome trace-event export path for the serving "
                          "spans + latency histograms")
     args = ap.parse_args()
+    compile_cache.enable()
     serve(args.arch, ckpt=args.ckpt, requests=args.requests,
           leads=[int(x) for x in args.leads.split(",")],
           mesh_data=args.mesh_data, precision=args.precision,

@@ -19,7 +19,8 @@ Three sections:
 
 ``--check`` is the CI mode: exit non-zero unless the file has a meta
 header and >= 1 step records whose mfu / comm_fraction / achieved_tflops
-are all finite and sane (0 <= mfu <= 1, 0 <= comm_fraction <= 1).
+are all finite and sane (0 <= mfu <= 1, 0 <= comm_fraction <= 1).  A
+record whose ``device`` is not a TPU has no mfu (no published peak).
 """
 from __future__ import annotations
 
@@ -94,8 +95,9 @@ def attribution(meta: Dict[str, Any], steps: List[Dict[str, Any]]
     mean_roll = _mean([float(r) for r in rolls])
     if not mean_dur or mean_dur <= 0:
         return None
-    t_comp = cm.get("t_compute_s", 0.0) * mean_roll
-    t_coll = cm.get("t_collective_s", 0.0) * mean_roll
+    # t_compute_s is None for a device with no published peak (CPU)
+    t_comp = (cm.get("t_compute_s") or 0.0) * mean_roll
+    t_coll = (cm.get("t_collective_s") or 0.0) * mean_roll
     data = min(_mean(waits) / mean_dur, 1.0)
     compute = min(t_comp / mean_dur, 1.0)
     collective = min(t_coll / mean_dur, 1.0)
@@ -129,6 +131,9 @@ def check(meta: Dict[str, Any], steps: List[Dict[str, Any]]) -> List[str]:
                             ("achieved_tflops", 0.0, float("inf")),
                             ("dur_s", 0.0, float("inf"))):
             v = s.get(key)
+            if v is None and key == "mfu" and not str(
+                    s.get("device", "TPU")).startswith("TPU"):
+                continue        # no published peak off the TPU: no MFU
             if v is None:
                 fails.append(f"step {i}: missing {key}")
             elif not math.isfinite(v):

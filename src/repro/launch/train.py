@@ -36,7 +36,7 @@ import argparse
 import sys
 
 from repro.configs.registry import ARCH_IDS
-from repro.launch import resilience
+from repro.launch import compile_cache, resilience
 from repro.launch.engine import EngineConfig, TrainEngine
 
 
@@ -156,6 +156,7 @@ def main():
             ap.error("--supervise requires --ckpt (the supervisor "
                      "discovers resume points under its directory)")
         sys.exit(resilience.supervise_train_cli(args, sys.argv[1:]))
+    compile_cache.enable()
     try:
         train(args.arch, steps=args.steps, batch=args.batch,
               seq_len=args.seq_len, reduced=not args.full,

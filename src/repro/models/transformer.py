@@ -28,7 +28,9 @@ from repro.core.api import DEFAULT_JIGSAW, JigsawConfig
 from repro.core.sharding import constrain
 from repro.models import layers as L
 
-FULL_WINDOW = jnp.int32(2 ** 30)   # sentinel: no sliding window
+# sentinel: no sliding window (a NumPy scalar: importing this module must
+# not start a JAX backend -- a supervising parent would claim the chip)
+FULL_WINDOW = np.int32(2 ** 30)
 
 
 def _norm_init(cfg: ModelConfig, d: int):

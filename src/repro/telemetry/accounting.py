@@ -77,7 +77,8 @@ class StepCostModel:
     bytes_per_hop: float           # wire bytes per hop per device
     wire_dtype_bytes: int
     approx_comm: bool              # True = non-mixer fallback estimate
-    peak_flops: float = A.PEAK_FLOPS_BF16
+    device: str = "TPU v5 lite"    # device_kind the peaks belong to
+    peak_flops: Optional[float] = A.PEAK_FLOPS_BF16   # None: no MFU
     ici_bw: float = A.ICI_BW
 
     @property
@@ -85,8 +86,11 @@ class StepCostModel:
         return max(self.n_model * self.n_data, 1)
 
     @property
-    def t_compute_s(self) -> float:
-        """Compute roofline term: per-device FLOPs at peak."""
+    def t_compute_s(self) -> Optional[float]:
+        """Compute roofline term: per-device FLOPs at peak (None on a
+        device with no published peak)."""
+        if self.peak_flops is None:
+            return None
         return self.flops_per_step / self.n_devices / self.peak_flops
 
     @property
@@ -97,19 +101,21 @@ class StepCostModel:
     def metrics(self, step_time_s: float,
                 rollout: int = 1) -> Dict[str, float]:
         """The derived fields of one step record, from a measured wall
-        duration.  All finite for any step_time_s > 0."""
+        duration.  All finite for any step_time_s > 0.  ``mfu`` only
+        where the device has a published peak: a CPU run has none."""
         if step_time_s <= 0:
-            return {"mfu": 0.0, "achieved_tflops": 0.0,
-                    "comm_fraction": 0.0}
-        r = max(int(rollout), 1)
-        achieved = (r * self.flops_per_step / self.n_devices
-                    / step_time_s)
-        return {
-            "mfu": achieved / self.peak_flops,
-            "achieved_tflops": achieved / 1e12,
-            "comm_fraction": min(1.0, r * self.t_collective_s
-                                 / step_time_s),
-        }
+            out = {"achieved_tflops": 0.0, "comm_fraction": 0.0}
+            achieved = 0.0
+        else:
+            r = max(int(rollout), 1)
+            achieved = (r * self.flops_per_step / self.n_devices
+                        / step_time_s)
+            out = {"achieved_tflops": achieved / 1e12,
+                   "comm_fraction": min(1.0, r * self.t_collective_s
+                                        / step_time_s)}
+        if self.peak_flops is not None:
+            out["mfu"] = achieved / self.peak_flops
+        return out
 
     def as_meta(self) -> Dict[str, Any]:
         """JSON-serializable constants for the trace JSONL header --
@@ -122,11 +128,14 @@ class StepCostModel:
 
 
 def build_cost_model(cfg, *, n_model: int = 1, n_data: int = 1,
-                     batch: int = 1, seq_len: int = 128,
-                     peak: float = A.PEAK_FLOPS_BF16,
+                     batch: int = 1, seq_len: int = 128, device=None,
                      ici: float = A.ICI_BW) -> StepCostModel:
     """Cost model for one training step of ``cfg`` on an
     (n_model x n_data) mesh with global batch ``batch``.
+
+    Peaks: ``device`` (the jax Device the run is on) picks its row of
+    ``launch/analysis.PEAKS`` -- none for a CPU, an error for a TPU kind
+    the table lacks.  ``device=None`` is the analytic v5e model.
 
     FLOPs: ``launch/analysis.flops_step(kind="train")`` (fwd + bwd, remat
     re-forward when configured) -- exact matmul dims.
@@ -140,6 +149,10 @@ def build_cost_model(cfg, *, n_model: int = 1, n_data: int = 1,
     (flagged ``approx_comm``)."""
     n_model = max(int(n_model), 1)
     n_data = max(int(n_data), 1)
+    if device is None:
+        kind, peaks = "TPU v5 lite", A.V5E
+    else:
+        kind, peaks = device.device_kind, A.peaks_for(device)
     flops = A.flops_step(cfg, "train", batch, seq_len)
     wire = _wire_dtype_bytes(cfg)
     scheme = cfg.scheme if n_model > 1 else "none"
@@ -177,8 +190,8 @@ def build_cost_model(cfg, *, n_model: int = 1, n_data: int = 1,
         n_model=n_model, n_data=n_data, batch=batch,
         flops_per_step=float(flops), comm_bytes_per_device=float(comm),
         hops=hops, bytes_per_hop=float(hop_bytes),
-        wire_dtype_bytes=wire, approx_comm=approx,
-        peak_flops=peak, ici_bw=ici)
+        wire_dtype_bytes=wire, approx_comm=approx, device=kind,
+        peak_flops=None if peaks is None else peaks.flops_bf16, ici_bw=ici)
 
 
 # ---------------------------------------------------------------------------

@@ -703,7 +703,7 @@ def test_ckpt_sharded_reshard_scenario():
     (no full-model gather) + save-on-8-way / restore-on-4-way."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run(
         [sys.executable, os.path.join(HERE, "dist_scenarios.py"),
          "ckpt_sharded_reshard"],

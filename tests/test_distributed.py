@@ -40,7 +40,7 @@ SCENARIOS = [
 def test_scenario(scenario):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run(
         [sys.executable, SCRIPT, scenario], env=env,
         capture_output=True, text=True, timeout=600)

@@ -264,7 +264,7 @@ def test_cli_supervise_preempt_and_resume(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env[resilience.PREEMPT_ENV] = "0"
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run(
         [sys.executable, "-m", "repro.launch.train",
          "--arch", "internlm2-1.8b", "--steps", "2", "--batch", "2",
@@ -280,9 +280,27 @@ def test_cli_supervise_preempt_and_resume(tmp_path):
         str(tmp_path / "ck")                  # final save outranks ck-0
 
 
+def test_supervisor_parent_stays_off_the_backend():
+    """The --supervise parent imports the training stack but must not
+    start a JAX backend: on a TPU host it would hold the chip its child
+    needs.  Importing never initialises one, and neither does the
+    checkpoint discovery the supervisor runs before every launch."""
+    code = ("import repro.launch.train\n"
+            "from repro.checkpoint import sharded\n"
+            "sharded.latest_checkpoint('.', prefix='ck')\n"
+            "from jax._src import xla_bridge\n"
+            "print(sorted(xla_bridge._backends))\n")
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
+    res = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert res.returncode == 0, res.stderr[-2000:]
+    assert res.stdout.strip() == "[]"
+
+
 def test_cli_supervise_requires_ckpt():
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run(
         [sys.executable, "-m", "repro.launch.train",
          "--arch", "internlm2-1.8b", "--steps", "1", "--supervise"],
@@ -296,7 +314,7 @@ def test_cli_supervise_requires_ckpt():
 def _run_scenario(name, timeout=900):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop(resilience.PREEMPT_ENV, None)
     res = subprocess.run(
         [sys.executable, os.path.join(HERE, "dist_scenarios.py"), name],

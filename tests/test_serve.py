@@ -400,7 +400,7 @@ def test_engine_serves_checkpoint(tmp_path):
 def test_serving_restore_scenario():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run(
         [sys.executable, os.path.join(HERE, "dist_scenarios.py"),
          "serving_restore"],

@@ -274,6 +274,46 @@ def test_fig7_point_pinned():
     assert p4["t_step_s"] < p2["t_step_s"] < p1["t_step_s"]
 
 
+class _Dev:
+    """Stand-in for a jax Device: only platform and device_kind matter."""
+
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_peaks_table_keyed_by_device_kind():
+    cfg = get_config(WM)
+    v5e = telemetry.build_cost_model(cfg, device=_Dev("tpu", "TPU v5 lite"))
+    assert v5e.device == "TPU v5 lite"
+    assert v5e.peak_flops == A.PEAKS["TPU v5 lite"].flops_bf16 == 197e12
+    assert A.PEAKS["TPU v5 lite"].hbm_bw == 819e9
+    assert "mfu" in v5e.metrics(1.0)
+    # an unknown TPU kind never borrows v5e's peaks
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        telemetry.build_cost_model(cfg, device=_Dev("tpu", "TPU v9 imaginary"))
+    # a CPU run names its device and carries no MFU
+    cpu = telemetry.build_cost_model(cfg, device=_Dev("cpu", "cpu"))
+    assert cpu.device == "cpu" and cpu.peak_flops is None
+    assert cpu.t_compute_s is None
+    m = cpu.metrics(1.0)
+    assert "mfu" not in m and m["achieved_tflops"] > 0
+
+
+def test_engine_step_records_name_their_device():
+    from repro.launch.engine import EngineConfig, TrainEngine
+    import jax
+    eng = TrainEngine("weathermixer-1b", config=EngineConfig(
+        steps=1, batch=1, log_every=1))
+    eng.run()
+    rec, = eng.tracer.step_records()
+    assert rec["device"] == jax.devices()[0].device_kind
+    if jax.devices()[0].platform != "tpu":
+        assert "mfu" not in rec
+    meta, steps, *_ = trace_report.split_records(
+        [dict(eng.tracer._meta, kind="meta"), dict(rec, kind="step")])
+    assert trace_report.check(meta, steps) == []
+
+
 def test_cost_model_mfu_8way():
     """wm-1b on an 8-way model mesh: the accounting identities the step
     records are built from."""
@@ -426,7 +466,7 @@ def test_telemetry_trace_scenario():
     here = os.path.dirname(__file__)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run(
         [sys.executable, os.path.join(here, "dist_scenarios.py"),
          "telemetry_trace"],
