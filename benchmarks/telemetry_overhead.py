@@ -99,7 +99,7 @@ def measure_primitives(n=20000):
     out["gauge"] = (time.perf_counter() - t0) / n * 1e6
     t0 = time.perf_counter()
     for i in range(n):
-        tr.step_record(step=i, dur_s=0.1, mfu=0.5)
+        tr.step_record(step=i, dur_s=0.1, data_wait_s=0.0)
     out["step_record"] = (time.perf_counter() - t0) / n * 1e6
     return out
 

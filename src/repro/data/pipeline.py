@@ -315,28 +315,54 @@ class InputPipeline:
         return NamedSharding(self.mesh, spec)
 
     # -- device-side ----------------------------------------------------
-    def get(self, step: int, horizon: int = 1) -> Dict[str, jax.Array]:
+    def get(self, step: int, horizon: int = 1, *,
+            wait: bool = False) -> Dict[str, jax.Array]:
         """The global (possibly sharded) device batch for ``step``.
+
+        The host reads come first, then the transfer.  With ``wait``
+        (the prefetch thread's call) the transfer runs in a
+        ``pipeline.h2d`` span that ends on ``block_until_ready`` of the
+        batch's arrays, so a queued batch is already on the device.
 
         Stats are collected locally while reading and committed in ONE
         ``record_batch`` call at the end -- the whole batch's accounting
         is a single critical section, so a concurrent stats reader never
         observes a half-applied batch."""
         reads: list = []
-        if self.mesh is None:
-            out = {k: jnp.asarray(v)
-                   for k, v in self.host_batch(step, horizon).items()}
-        elif self.mode == "sync-full":
-            hb = self.host_batch(step, horizon)
-            reads.extend((k, -1, v.nbytes, True) for k, v in hb.items())
-            out = {k: jax.device_put(jnp.asarray(v),
-                                     self._sharding_for(k, v.shape))
-                   for k, v in hb.items()}
+        host = self._read(step, horizon, reads)
+        if wait:
+            with telemetry.get_tracer().span("pipeline.h2d", step=step):
+                out = jax.block_until_ready(
+                    {k: self._put(k, v) for k, v in host.items()})
         else:
-            out = {k: self._assemble(k, step, horizon, reads)
-                   for k in self.source.keys}
+            out = {k: self._put(k, v) for k, v in host.items()}
         self.stats.record_batch(reads, steps=1)
         return out
+
+    def _read(self, step: int, horizon: int, reads: list) -> Dict:
+        """The batch on the host, per key: whole arrays, or under
+        ``sharded`` on a mesh each unique slice of the read plan with
+        the devices that replicate it."""
+        if self.mesh is not None and self.mode == "sharded":
+            return {k: self._read_slices(k, step, horizon, reads)
+                    for k in self.source.keys}
+        hb = self.host_batch(step, horizon)
+        if self.mesh is not None:
+            reads.extend((k, -1, v.nbytes, True) for k, v in hb.items())
+        return hb
+
+    def _put(self, key: str, host) -> jax.Array:
+        """One key's host data onto the device(s)."""
+        if self.mesh is None:
+            return jnp.asarray(host)
+        if self.mode == "sync-full":
+            return jax.device_put(jnp.asarray(host),
+                                  self._sharding_for(key, host.shape))
+        plan = self._plan_for(key)
+        arrays = [jax.device_put(buf, dev)
+                  for buf, devs in host for dev in devs]
+        return jax.make_array_from_single_device_arrays(
+            plan.shape, plan.sharding, arrays)
 
     def _plan_for(self, key: str) -> _ReadPlan:
         """The (cached) per-host read plan for ``key``: unique slices
@@ -357,22 +383,20 @@ class InputPipeline:
             self.stats.record_batch([], plan_builds=1)
         return plan
 
-    def _assemble(self, key: str, step: int, horizon: int,
-                  reads: list) -> jax.Array:
-        """Build the global array from per-host partitioned reads: each
-        unique slice in the plan is generated ONCE and fanned out to
-        every device that replicates it.  Read records are appended to
-        ``reads`` for the caller's one-shot ``record_batch``."""
-        plan = self._plan_for(key)
-        arrays = []
-        for nidx, devs in plan.reads:
+    def _read_slices(self, key: str, step: int, horizon: int,
+                     reads: list) -> list:
+        """Per-host partitioned reads of ``key``: each unique slice in
+        the plan is generated ONCE, for every device that replicates it
+        (``_put`` fans it out).  Read records are appended to ``reads``
+        for the caller's one-shot ``record_batch``."""
+        out = []
+        for nidx, devs in self._plan_for(key).reads:
             buf = np.ascontiguousarray(
                 self.source.read_key(key, step, horizon, nidx))
-            for j, dev in enumerate(devs):
-                reads.append((key, dev.id, buf.nbytes, j == 0))
-                arrays.append(jax.device_put(buf, dev))
-        return jax.make_array_from_single_device_arrays(
-            plan.shape, plan.sharding, arrays)
+            reads.extend((key, dev.id, buf.nbytes, j == 0)
+                         for j, dev in enumerate(devs))
+            out.append((buf, devs))
+        return out
 
     # -- resume state ----------------------------------------------------
     def state(self) -> Dict[str, int]:
@@ -391,8 +415,9 @@ class InputPipeline:
         rollout horizons ``horizons[i]``.  ``start_step=None`` continues
         from the pipeline's cursor (0 on a fresh pipeline, the restored
         step after a resume).  With ``prefetch > 0`` a daemon thread
-        generates and transfers batches ahead of the consumer; values
-        are identical either way (pure function of the step)."""
+        generates and transfers batches ahead of the consumer and queues
+        each once it is on the device (``pipeline.h2d``); values are
+        identical either way (pure function of the step)."""
         n = len(horizons)
         if start_step is None:
             start_step = self.cursor
@@ -413,7 +438,8 @@ class InputPipeline:
                     if stop.is_set():
                         return
                     with tr.span("pipeline.produce", step=start_step + i):
-                        batch = self.get(start_step + i, int(horizons[i]))
+                        batch = self.get(start_step + i, int(horizons[i]),
+                                         wait=True)
                     while not stop.is_set():
                         # bounded put: never blocks forever against a
                         # consumer that has already given up (stop()

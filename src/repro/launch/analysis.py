@@ -46,8 +46,8 @@ ICI_BW = 50e9                 # bytes/s per link (~usable per-chip here)
 def peaks_for(device) -> Optional[Peaks]:
     """Peaks of the chip a run is on (a jax ``Device``).
 
-    None off the TPU: a CPU has no entry, so its step records carry no
-    MFU.  A TPU kind missing from ``PEAKS`` raises -- an unknown chip
+    None off the TPU: a CPU has no entry, so its cost model has no
+    compute roofline term.  A TPU kind missing from ``PEAKS`` raises -- an unknown chip
     never borrows another chip's peaks."""
     if device.platform != "tpu":
         return None

@@ -141,10 +141,12 @@ class TrainEngine:
 
         # telemetry (DESIGN.md §14): the engine owns the process tracer;
         # the pipeline / checkpoint writer / resilience hooks report
-        # into it via telemetry.get_tracer().  The analytic cost model
-        # turns each step's wall time into mfu / comm_fraction /
-        # achieved_tflops (telemetry/accounting.py).
-        self.tracer = telemetry.Tracer(enabled=config.telemetry)
+        # into it via telemetry.get_tracer(), its spans show in any
+        # jax.profiler trace and backend compiles land in it as spans.
+        # The analytic cost model's counts go into the meta header
+        # (telemetry/accounting.py); step records hold measured fields.
+        self.tracer = telemetry.attach_profiler(
+            telemetry.Tracer(enabled=config.telemetry))
         telemetry.set_tracer(self.tracer)
         device = (self.mesh.devices.flat[0] if self.use_mesh
                   else jax.devices()[0])
@@ -347,8 +349,7 @@ class TrainEngine:
                         tr.step_record(
                             step=i, rollout=r, dur_s=wall,
                             data_wait_s=dw.dur_s,
-                            device=self.cost_model.device,
-                            **self.cost_model.metrics(wall, rollout=r))
+                            device=self.cost_model.device)
                         if i % c.log_every == 0 or i == c.steps - 1:
                             m = {k: float(v) for k, v in metrics.items()}
                             m["step"] = i

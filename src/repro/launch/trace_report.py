@@ -4,23 +4,17 @@ into a human-readable run report (DESIGN.md §14):
   PYTHONPATH=src python -m repro.launch.trace_report out.trace.jsonl
   PYTHONPATH=src python -m repro.launch.trace_report out.trace.jsonl --check
 
-Three sections:
+Sections, all from measured records:
 
-  1. step-time breakdown -- per-step wall / data-wait / mfu /
-     comm_fraction aggregates over the run's step records;
-  2. span table -- every span name with count / total / mean, straight
-     from the tracer's span summary;
-  3. roofline attribution -- the measured mean step time split into the
-     analytic compute and collective terms of the run's
-     ``StepCostModel`` (stamped into the meta header) plus the measured
-     data-wait share, ending in a one-line verdict ("this run was 31%
-     data-bound"): the Fig. 7 regime classification applied to a real
-     trace instead of the analytic model.
+  1. steps -- per-step wall time (submit to submit) and data wait over
+     the run's step records;
+  2. spans -- every span name with count / total / mean, straight from
+     the tracer's span summary;
+  3. counters and latency histograms.
 
 ``--check`` is the CI mode: exit non-zero unless the file has a meta
-header and >= 1 step records whose mfu / comm_fraction / achieved_tflops
-are all finite and sane (0 <= mfu <= 1, 0 <= comm_fraction <= 1).  A
-record whose ``device`` is not a TPU has no mfu (no published peak).
+header and >= 1 step records whose ``dur_s`` and ``data_wait_s`` are
+present, finite and not negative.
 """
 from __future__ import annotations
 
@@ -73,48 +67,7 @@ def _pct(xs: List[float], p: float) -> float:
     return s[min(len(s) - 1, int(p * len(s)))]
 
 
-def attribution(meta: Dict[str, Any], steps: List[Dict[str, Any]]
-                ) -> Optional[Dict[str, float]]:
-    """Mean-step-time shares: data / compute / collective / other.
-
-    ``data`` is measured (the data_wait span -- time the consumer
-    actually stalled on the input pipeline); compute and collective are
-    the cost model's analytic roofline terms scaled by the step's
-    rollout; ``other`` is whatever the model does not explain
-    (dispatch, host overhead, py loop).  Shares are clamped to [0, 1]
-    of the mean step time."""
-    cm = meta.get("cost_model")
-    if not cm or not steps:
-        return None
-    durs = [s["dur_s"] for s in steps if "dur_s" in s]
-    waits = [s.get("data_wait_s", 0.0) for s in steps]
-    rolls = [max(int(s.get("rollout", 1)), 1) for s in steps]
-    if not durs:
-        return None
-    mean_dur = _mean(durs)
-    mean_roll = _mean([float(r) for r in rolls])
-    if not mean_dur or mean_dur <= 0:
-        return None
-    # t_compute_s is None for a device with no published peak (CPU)
-    t_comp = (cm.get("t_compute_s") or 0.0) * mean_roll
-    t_coll = (cm.get("t_collective_s") or 0.0) * mean_roll
-    data = min(_mean(waits) / mean_dur, 1.0)
-    compute = min(t_comp / mean_dur, 1.0)
-    collective = min(t_coll / mean_dur, 1.0)
-    other = max(0.0, 1.0 - data - compute - collective)
-    return {"mean_step_s": mean_dur, "data": data, "compute": compute,
-            "collective": collective, "other": other}
-
-
-def verdict(att: Dict[str, float]) -> str:
-    shares = {"data": att["data"], "compute": att["compute"],
-              "comm": att["collective"], "overhead": att["other"]}
-    name = max(shares, key=shares.get)
-    return (f"this run was {shares[name] * 100:.0f}% {name}-bound "
-            f"(data {att['data'] * 100:.0f}% / "
-            f"compute {att['compute'] * 100:.0f}% / "
-            f"comm {att['collective'] * 100:.0f}% / "
-            f"other {att['other'] * 100:.0f}%)")
+STEP_FIELDS = ("dur_s", "data_wait_s")
 
 
 def check(meta: Dict[str, Any], steps: List[Dict[str, Any]]) -> List[str]:
@@ -126,20 +79,14 @@ def check(meta: Dict[str, Any], steps: List[Dict[str, Any]]) -> List[str]:
         fails.append("no step records")
     for s in steps:
         i = s.get("step", "?")
-        for key, lo, hi in (("mfu", 0.0, 1.0),
-                            ("comm_fraction", 0.0, 1.0),
-                            ("achieved_tflops", 0.0, float("inf")),
-                            ("dur_s", 0.0, float("inf"))):
+        for key in STEP_FIELDS:
             v = s.get(key)
-            if v is None and key == "mfu" and not str(
-                    s.get("device", "TPU")).startswith("TPU"):
-                continue        # no published peak off the TPU: no MFU
             if v is None:
                 fails.append(f"step {i}: missing {key}")
             elif not math.isfinite(v):
                 fails.append(f"step {i}: {key}={v} not finite")
-            elif not (lo <= v <= hi):
-                fails.append(f"step {i}: {key}={v} outside [{lo}, {hi}]")
+            elif v < 0:
+                fails.append(f"step {i}: {key}={v} negative")
     return fails
 
 
@@ -157,24 +104,17 @@ def render(path: str, out=sys.stdout) -> None:
         w("run: " + " ".join(f"{k}={v}" for k, v in head.items()) + "\n")
 
     if steps:
-        durs = [s["dur_s"] for s in steps if "dur_s" in s]
-        waits = [s.get("data_wait_s", 0.0) for s in steps]
-        mfus = [s.get("mfu") for s in steps if s.get("mfu") is not None]
-        comms = [s.get("comm_fraction") for s in steps
-                 if s.get("comm_fraction") is not None]
-        tf = [s.get("achieved_tflops") for s in steps
-              if s.get("achieved_tflops") is not None]
-        w(f"\n-- steps ({len(steps)}) --\n")
-        w(f"{'metric':<18}{'mean':>12}{'p50':>12}{'p95':>12}\n")
-        for name, xs, scale in (("step_s", durs, 1.0),
-                                ("data_wait_s", waits, 1.0),
-                                ("mfu", mfus, 1.0),
-                                ("comm_fraction", comms, 1.0),
-                                ("achieved_tflops", tf, 1.0)):
+        devices = sorted({str(s["device"]) for s in steps
+                          if "device" in s})
+        w(f"\n-- steps ({len(steps)}"
+          + (f", on {', '.join(devices)}" if devices else "") + ") --\n")
+        w(f"{'field':<18}{'mean':>12}{'p50':>12}{'p95':>12}"
+          f"{'total':>12}\n")
+        for name in STEP_FIELDS:
+            xs = [s[name] for s in steps if name in s]
             if xs:
-                w(f"{name:<18}{_mean(xs) * scale:>12.4g}"
-                  f"{_pct(xs, 0.5) * scale:>12.4g}"
-                  f"{_pct(xs, 0.95) * scale:>12.4g}\n")
+                w(f"{name:<18}{_mean(xs):>12.4g}{_pct(xs, 0.5):>12.4g}"
+                  f"{_pct(xs, 0.95):>12.4g}{sum(xs):>12.4g}\n")
 
     if spans:
         w(f"\n-- spans --\n")
@@ -198,16 +138,6 @@ def render(path: str, out=sys.stdout) -> None:
             w(f"{h['name']:<32}{h['count']:>8}{h.get('p50', 0):>12.4g}"
               f"{h.get('p95', 0):>12.4g}{h.get('p99', 0):>12.4g}\n")
 
-    att = attribution(meta, steps)
-    if att:
-        w(f"\n-- roofline attribution --\n")
-        w(f"mean step {att['mean_step_s'] * 1e3:.2f} ms = "
-          f"data {att['data'] * 100:.1f}% + "
-          f"compute {att['compute'] * 100:.1f}% + "
-          f"comm {att['collective'] * 100:.1f}% + "
-          f"other {att['other'] * 100:.1f}%\n")
-        w(verdict(att) + "\n")
-
 
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser()
@@ -215,8 +145,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                                   "of --trace's Chrome JSON)")
     ap.add_argument("--check", action="store_true",
                     help="CI mode: exit 1 unless every step record has "
-                         "finite, in-range mfu / comm_fraction / "
-                         "achieved_tflops")
+                         "finite, non-negative dur_s and data_wait_s")
     args = ap.parse_args(argv)
     meta, steps, *_ = split_records(load_records(args.jsonl))
     if args.check:
@@ -225,8 +154,8 @@ def main(argv: Optional[List[str]] = None) -> int:
             for f in fails:
                 print(f"[trace-check] FAIL: {f}")
             return 1
-        print(f"[trace-check] OK: {len(steps)} step records, all "
-              f"derived metrics finite and in range")
+        print(f"[trace-check] OK: {len(steps)} step records, every "
+              f"measured field finite and non-negative")
         return 0
     render(args.jsonl)
     return 0

@@ -125,7 +125,7 @@ def main():
     ap.add_argument("--trace", default=None,
                     help="Chrome trace-event export path (load in "
                          "Perfetto); a sibling .jsonl gets the per-step "
-                         "mfu/comm_fraction records for "
+                         "records (wall time, data wait) for "
                          "launch/trace_report.py")
     ap.add_argument("--no-telemetry", action="store_true",
                     help="disable span tracing (the overhead benchmark's "

@@ -102,7 +102,8 @@ class ForecastEngine:
         # engine-local tracer: admission-to-delivery histograms (one per
         # lead time) + serve spans; not the process tracer, so several
         # engines in one process (A/B benchmarks) never mix percentiles
-        self.tracer = telemetry.Tracer(enabled=config.telemetry)
+        self.tracer = telemetry.attach_profiler(
+            telemetry.Tracer(enabled=config.telemetry))
         self.tracer.set_meta(surface="serve", arch=arch, reduced=reduced,
                              mesh_data=mesh_data, mode=config.mode,
                              buckets=list(config.buckets))
@@ -138,6 +139,7 @@ class ForecastEngine:
         self._grow_fns = {}         # (b_from, b_to) -> jitted pad
         self._state = None
         self._bucket = 0
+        self._idle_ns: Optional[int] = None  # open serve.idle period
         self._stop = threading.Event()
         self._wake = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -256,7 +258,8 @@ class ForecastEngine:
         if fields.shape != self.field_shape:
             raise ValueError(f"fields shape {fields.shape} != "
                              f"{self.field_shape}")
-        req = ForecastResult(fields, leads, submit_t=self._clock())
+        req = ForecastResult(fields, leads, submit_t=self._clock(),
+                             submit_ns=self.tracer.now_ns())
         self.sched.submit(req)
         self._wake.set()
         return req
@@ -265,14 +268,32 @@ class ForecastEngine:
         """Advance one rollout-step boundary.
 
         Returns "idle" (nothing to do), "wait" (coalescing window still
-        open) or "step" (one device rollout step ran)."""
+        open) or "step" (one device rollout step ran).
+
+        Spans name what the boundary waits on: ``serve.idle`` runs from
+        the first boundary that found no request queued or in flight to
+        the next one that found some, and is recorded when that one
+        starts; ``serve.queue`` runs from a request's ``submit`` to its
+        admission, on the submitting thread's track; in ``serve.peel``,
+        ``serve.peel.wait`` is the device finishing the step and the
+        slice, ``serve.peel.fetch`` the copy to the host."""
+        tr = self.tracer
+        t_ns = tr.now_ns()
         tick = self.sched.tick()
         if tick.idle:
+            if self._idle_ns is None:
+                self._idle_ns = t_ns
             return "idle"
+        if self._idle_ns is not None:
+            tr.complete("serve.idle", self._idle_ns, t_ns)
+            self._idle_ns = None
         if tick.wait is not None:
             self.stats["wait_ticks"] += 1
             return "wait"
-        tr = self.tracer
+        t_ns = tr.now_ns()             # admission: after every submit_ns
+        for _, req in tick.admit:
+            tr.complete("serve.queue", req.submit_ns, t_ns,
+                        tid=req.submit_tid, rid=req.rid)
         if tick.form is not None:
             with tr.span("serve.form", bucket=tick.form):
                 self._state = self._fns(tick.form)["zeros"]()
@@ -286,7 +307,8 @@ class ForecastEngine:
         fns = self._fns(self._bucket)
         if tick.admit:
             with tr.span("serve.admit", n=len(tick.admit),
-                         bucket=self._bucket):
+                         bucket=self._bucket,
+                         rids=[req.rid for _, req in tick.admit]):
                 for slot, req in tick.admit:
                     self._state = fns["admit"](self._state,
                                                self._put_fields(req.fields),
@@ -298,8 +320,12 @@ class ForecastEngine:
         peels, _finished = self.sched.advance()
         now = self._clock()
         for slot, req, lead in peels:
-            with tr.span("serve.peel", lead=lead):
-                out = np.asarray(fns["peel"](self._state, np.int32(slot)))
+            with tr.span("serve.peel", lead=lead, rid=req.rid):
+                piece = fns["peel"](self._state, np.int32(slot))
+                with tr.span("serve.peel.wait"):
+                    jax.block_until_ready(piece)
+                with tr.span("serve.peel.fetch"):
+                    out = np.asarray(piece)
             req.deliver(lead, out, now)
             # admission-to-delivery latency histograms: the engine's
             # serving SLO, one track per lead time plus the overall one

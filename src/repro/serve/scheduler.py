@@ -44,11 +44,16 @@ class ForecastResult:
     are free, they ride the same rollout.
     """
 
-    def __init__(self, fields, leads: Tuple[int, ...], submit_t: float):
+    def __init__(self, fields, leads: Tuple[int, ...], submit_t: float,
+                 submit_ns: int = 0):
         self.fields = fields                     # host array [lat, lon, C]
         self.leads = leads                       # sorted, unique, >= 1
         self.rid = next(_RID)
         self.submit_t = submit_t
+        # where the engine's serve.queue span starts: a reading of its
+        # tracer's clock, on the submitting thread's track
+        self.submit_ns = submit_ns
+        self.submit_tid = threading.get_ident()
         self.start_t: Optional[float] = None     # admission time
         self.done_t: Optional[float] = None
         self.outputs: Dict[int, object] = {}     # lead -> fields array

@@ -1,24 +1,16 @@
-"""Analytic FLOP / wire-byte accounting behind every step record
-(DESIGN.md §14).
+"""Analytic FLOP / wire-byte counts of a training step (DESIGN.md §14).
 
-The paper's headline numbers -- achieved PFLOPs, percent-of-peak,
-communication share -- are *derived* quantities: a wall-clock step time
-divided into an analytic cost model.  This module builds that model once
-per (ModelConfig, Jigsaw scheme, mesh shape) and turns each measured
-step duration into
-
-  ``mfu``               achieved FLOP/s per device / peak FLOP/s,
-  ``achieved_tflops``   achieved TFLOP/s per device,
-  ``comm_fraction``     modeled collective seconds / measured step
-                        seconds (the share of the step the Jigsaw wire
-                        traffic accounts for at ICI bandwidth),
-
-plus the per-hop wire bytes of the explicit ring schedule
-(``core.jigsaw.comm_schedule_jigsaw_1d`` -- the same schedule the fused
-kernel enforces).  The FLOPs side reuses ``launch/analysis.py``'s exact
-matmul-dims model; the roofline terms are the same formulas as
-``benchmarks/fig7_roofline.py`` (``fig7_point`` below reproduces that
-benchmark's rows bit-for-bit, pinned by tests/test_telemetry.py).
+``build_cost_model`` prices one step once per (ModelConfig, Jigsaw
+scheme, mesh shape): global FLOPs, the per-hop wire bytes of the
+explicit ring schedule (``core.jigsaw.comm_schedule_jigsaw_1d`` -- the
+same schedule the fused kernel enforces), and the compute and
+collective roofline terms they imply.  These are counts, not
+measurements: the engine stamps them into the run's meta header
+(``as_meta``) and no step record is derived from them.  The FLOPs side
+reuses ``launch/analysis.py``'s exact matmul-dims model; the roofline
+terms are the same formulas as ``benchmarks/fig7_roofline.py``
+(``fig7_point`` below reproduces that benchmark's rows bit-for-bit,
+pinned by tests/test_telemetry.py).
 
 ``hlo_collective_bytes`` cross-checks the analytic wire model against a
 compiled step's actual HLO collectives (``launch/analysis.py`` parse).
@@ -63,8 +55,7 @@ class StepCostModel:
     """Analytic per-step costs for one (config, scheme, mesh) triple.
 
     ``flops_per_step`` / ``comm_bytes_per_device`` are for ONE rollout
-    step (rollout=1); ``metrics`` scales both by the step's actual
-    rollout length."""
+    step (rollout=1)."""
     arch: str
     scheme: str
     impl: str
@@ -78,7 +69,7 @@ class StepCostModel:
     wire_dtype_bytes: int
     approx_comm: bool              # True = non-mixer fallback estimate
     device: str = "TPU v5 lite"    # device_kind the peaks belong to
-    peak_flops: Optional[float] = A.PEAK_FLOPS_BF16   # None: no MFU
+    peak_flops: Optional[float] = A.PEAK_FLOPS_BF16   # None: no peak
     ici_bw: float = A.ICI_BW
 
     @property
@@ -98,28 +89,8 @@ class StepCostModel:
         """Collective roofline term: per-device wire bytes at ICI BW."""
         return self.comm_bytes_per_device / self.ici_bw
 
-    def metrics(self, step_time_s: float,
-                rollout: int = 1) -> Dict[str, float]:
-        """The derived fields of one step record, from a measured wall
-        duration.  All finite for any step_time_s > 0.  ``mfu`` only
-        where the device has a published peak: a CPU run has none."""
-        if step_time_s <= 0:
-            out = {"achieved_tflops": 0.0, "comm_fraction": 0.0}
-            achieved = 0.0
-        else:
-            r = max(int(rollout), 1)
-            achieved = (r * self.flops_per_step / self.n_devices
-                        / step_time_s)
-            out = {"achieved_tflops": achieved / 1e12,
-                   "comm_fraction": min(1.0, r * self.t_collective_s
-                                        / step_time_s)}
-        if self.peak_flops is not None:
-            out["mfu"] = achieved / self.peak_flops
-        return out
-
     def as_meta(self) -> Dict[str, Any]:
-        """JSON-serializable constants for the trace JSONL header --
-        enough for ``trace_report`` to recompute every derived field."""
+        """JSON-serializable constants for the trace JSONL header."""
         d = dataclasses.asdict(self)
         d["t_compute_s"] = self.t_compute_s
         d["t_collective_s"] = self.t_collective_s
@@ -202,7 +173,7 @@ def fig7_point(cfg, way: int, impl: Optional[str] = None
                ) -> Dict[str, float]:
     """One row of the Fig. 7 roofline, exactly as
     ``benchmarks/fig7_roofline.py`` computes it (same formulas, same
-    constants) -- the pinned reference for the MFU accounting test.
+    constants) -- the pinned reference for the roofline terms.
 
     Returns t_step_s / tflops_per_dev / peak_frac / regime for a mixer
     config at jigsaw width ``way`` (1, 2 = 1-D ring, 4 = 2-D Cannon);

@@ -19,14 +19,24 @@ The process-wide observability primitive every subsystem reports into:
     plotted track in Perfetto.
   * ``observe`` -- histogram samples with ``percentile``/``hist_summary``
     readouts (the serving engine's admission-to-delivery latencies).
+  * ``complete(name, t0_ns, t1_ns)`` -- a span whose start and end were
+    read earlier on the tracer's clock (``now_ns``): a period that only
+    closes at a later call (the serving engine's ``serve.idle``), a
+    request's queue wait recorded on its submitting thread's track, a
+    compile reported after it finished.
   * ``step_record`` -- one structured dict per training step (the JSONL
-    rows ``launch/trace_report.py`` renders; ``accounting.py`` computes
-    their mfu / comm_fraction / achieved_tflops fields).
+    rows ``launch/trace_report.py`` renders).
+
+With an ``annotate`` factory (``jax.profiler.TraceAnnotation``, handed
+over by ``telemetry/profiler.attach``) every ``span`` also enters an
+annotation of its name, so the spans sit on the host plane of any
+``jax.profiler`` trace, on the device trace's clock.
 
 Everything is guarded by one lock per tracer and costs O(µs) per call;
-a disabled tracer (``enabled=False``) skips event recording but keeps
-counters/gauges live, so subsystems can always report through it.
-``benchmarks/telemetry_overhead.py`` holds the <2 % overhead budget.
+a disabled tracer (``enabled=False``) skips event recording and
+annotation but keeps counters/gauges live, so subsystems can always
+report through it.  ``benchmarks/telemetry_overhead.py`` holds the
+<2 % overhead budget.
 
 Zero dependencies beyond the standard library; never imports jax.
 """
@@ -38,11 +48,19 @@ import json
 import os
 import threading
 import time
-from typing import Any, Dict, Iterable, List, Mapping, Optional, Tuple
+from typing import (Any, Callable, ContextManager, Dict, List, Mapping,
+                    Optional)
 
 
 def _monotonic_ns() -> int:
     return time.perf_counter_ns()
+
+
+def _thread_name(tid: int) -> str:
+    for t in threading.enumerate():
+        if t.ident == tid:
+            return t.name
+    return f"thread-{tid}"
 
 
 class Span:
@@ -50,7 +68,8 @@ class Span:
     readable after the ``with`` block exits (the engine feeds its
     data-wait durations into the step records this way)."""
 
-    __slots__ = ("name", "args", "t0_ns", "dur_ns", "tid", "_tracer")
+    __slots__ = ("name", "args", "t0_ns", "dur_ns", "tid", "_tracer",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
@@ -59,12 +78,17 @@ class Span:
         self.t0_ns = 0
         self.dur_ns = 0
         self.tid = 0
+        self._ann = None
 
     @property
     def dur_s(self) -> float:
         return self.dur_ns / 1e9
 
     def __enter__(self) -> "Span":
+        annotate = self._tracer.annotate
+        if annotate is not None:
+            self._ann = annotate(self.name)
+            self._ann.__enter__()
         self.t0_ns = _monotonic_ns()
         self._tracer._push(self)
         return self
@@ -72,6 +96,8 @@ class Span:
     def __exit__(self, *exc) -> None:
         self.dur_ns = _monotonic_ns() - self.t0_ns
         self._tracer._pop(self)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
 
 
 class _NullSpan:
@@ -108,11 +134,18 @@ class Tracer:
     ring : maximum buffered events (a per-process ring: the newest
         ``ring`` events win -- a multi-day run cannot OOM the host).
     max_hist : per-histogram sample cap (newest samples win).
+
+    ``annotate``, None until ``telemetry/profiler.attach`` sets it, is a
+    factory of a context manager taking a span's name: each enabled
+    ``span`` also enters one (the profiler annotation).
     """
+
+    now_ns = staticmethod(_monotonic_ns)   # the clock of every event
 
     def __init__(self, *, enabled: bool = True, ring: int = 200_000,
                  max_hist: int = 100_000):
         self.enabled = enabled
+        self.annotate: Optional[Callable[[str], ContextManager]] = None
         self.lock = threading.Lock()
         self.pid = os.getpid()
         self.t0_ns = _monotonic_ns()
@@ -159,6 +192,23 @@ class Tracer:
             if span.tid not in tn:
                 t = threading.current_thread()
                 tn[span.tid] = t.name
+
+    def complete(self, name: str, t0_ns: int, t1_ns: int, *,
+                 tid: Optional[int] = None, **args) -> None:
+        """Record a finished span from two ``now_ns()`` readings, on the
+        track of thread ``tid`` (default: the calling thread, at its
+        current nesting depth).  Lands in the ring as ``span`` does."""
+        if not self.enabled:
+            return
+        here = threading.get_ident()
+        if tid is None:
+            tid = here
+        depth = len(self._stack()) if tid == here else 0
+        with self.lock:
+            self._events.append(("X", name, t0_ns - self.t0_ns,
+                                 t1_ns - t0_ns, tid, depth, args or None))
+            if tid not in self._thread_names:
+                self._thread_names[tid] = _thread_name(tid)
 
     def current_span(self) -> Optional[Span]:
         st = self._stack()

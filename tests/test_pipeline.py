@@ -109,6 +109,40 @@ def test_pipeline_prefetch_propagates_errors():
             pass
 
 
+def test_pipeline_h2d_span_on_the_prefetch_thread():
+    """The transfer and its wait sit in a pipeline.h2d span inside
+    pipeline.produce on the prefetch thread, one per batch; the consumer
+    and a pipeline without prefetch never block on it."""
+    import threading
+    from repro import telemetry
+    from repro.configs.registry import get_config
+    cfg = get_config("weathermixer-1b").reduced()
+    tr = telemetry.Tracer()
+    prev = telemetry.set_tracer(tr)
+    try:
+        pref = make_pipeline(cfg, batch_size=2, prefetch=2)
+        assert len(list(pref.iterate([1, 2, 1]))) == 3
+        xs = [e for e in tr.chrome_events() if e.get("ph") == "X"]
+        h2d = [e for e in xs if e["name"] == "pipeline.h2d"]
+        produce = [e for e in xs if e["name"] == "pipeline.produce"]
+        assert len(h2d) == len(produce) == 3
+        main = threading.get_ident()
+        for e in h2d:
+            assert e["tid"] != main
+            assert sum(p["tid"] == e["tid"] and p["ts"] <= e["ts"]
+                       and e["ts"] + e["dur"] <= p["ts"] + p["dur"] + 1e-3
+                       for p in produce) == 1
+        names = {e["tid"]: e["args"]["name"] for e in tr.chrome_events()
+                 if e.get("ph") == "M" and e["name"] == "thread_name"}
+        assert {names[e["tid"]] for e in h2d} == {"input-pipeline"}
+        sync = make_pipeline(cfg, batch_size=2, prefetch=0)
+        list(sync.iterate([1, 1]))
+        assert len([e for e in tr.chrome_events()
+                    if e.get("name") == "pipeline.h2d"]) == 3
+    finally:
+        telemetry.set_tracer(prev)
+
+
 def test_pipeline_rejects_bad_mode():
     src = WeatherBatchSource(WeatherDataset(WCFG), batch_size=2)
     with pytest.raises(ValueError):

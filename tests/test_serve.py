@@ -240,6 +240,128 @@ def test_engine_coalescing_with_fake_clock():
     assert eng.sched.counters["formed"] == 1
 
 
+def _xs(eng, name):
+    return [e for e in eng.tracer.chrome_events()
+            if e.get("ph") == "X" and e["name"] == name]
+
+
+def _inside(child, parent):
+    return (parent["ts"] <= child["ts"] and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + 1e-3)
+
+
+def _overlap(a, b):
+    return (min(a["ts"] + a["dur"], b["ts"] + b["dur"])
+            - max(a["ts"], b["ts"]))
+
+
+def test_engine_idle_span_between_spaced_requests():
+    """serve.idle covers the boundaries with no request queued or in
+    flight -- recorded when the next boundary finds work -- and never a
+    boundary's device work."""
+    import time
+    eng = tiny_engine()
+    eng.warmup()
+    fs = _fields(2, eng)
+    r1 = eng.submit(fs[0], 2)
+    while not r1.done():
+        assert eng.step_once() == "step"
+    assert _xs(eng, "serve.idle") == []   # no period closed yet
+    for _ in range(3):
+        assert eng.step_once() == "idle"
+        time.sleep(0.01)
+    assert _xs(eng, "serve.idle") == []   # still open: not recorded
+    r2 = eng.submit(fs[1], 1)
+    eng.drain()
+    assert r2.done()
+    idle, = _xs(eng, "serve.idle")
+    assert idle["dur"] >= 3 * 0.01 * 1e6 * 0.9
+    peel1, peel2 = sorted(_xs(eng, "serve.peel"), key=lambda e: e["ts"])
+    assert peel1["ts"] + peel1["dur"] <= idle["ts"]
+    assert idle["ts"] + idle["dur"] <= peel2["ts"]
+    busy = [e for n in ("serve.form", "serve.grow", "serve.admit",
+                        "serve.step", "serve.peel") for e in _xs(eng, n)]
+    assert busy and all(_overlap(idle, e) <= 0 for e in busy)
+    # on the thread that calls step_once, at the top of its stack
+    assert idle["tid"] == peel1["tid"]
+
+
+def test_engine_peel_split_nests_in_each_peel():
+    eng = tiny_engine()
+    eng.warmup()
+    fs = _fields(3, eng, seed=4)
+    rs = [eng.submit(fs[i], lead) for i, lead in enumerate((1, 2, (1, 3)))]
+    eng.drain()
+    assert all(r.done() for r in rs)
+    peels = _xs(eng, "serve.peel")
+    assert len(peels) == 4                 # leads 1, 2, 1 and 3
+    assert {p["args"]["rid"] for p in peels} == {r.rid for r in rs}
+    for part in ("serve.peel.wait", "serve.peel.fetch"):
+        got = _xs(eng, part)
+        assert len(got) == len(peels)
+        for p in peels:
+            assert sum(e["tid"] == p["tid"] and _inside(e, p)
+                       for e in got) == 1
+    for p in peels:
+        wait, = [e for e in _xs(eng, "serve.peel.wait") if _inside(e, p)]
+        fetch, = [e for e in _xs(eng, "serve.peel.fetch") if _inside(e, p)]
+        assert wait["ts"] + wait["dur"] <= fetch["ts"] + 1e-3
+
+
+def test_engine_queue_span_per_request():
+    """Each request gets one serve.queue span, from submit to admission,
+    with its rid, on the submitting thread's track; serve.admit names
+    the rids it admitted."""
+    import threading
+    eng = tiny_engine(config=ServeConfig(buckets=(1, 2)))
+    eng.warmup()
+    fs = _fields(3, eng, seed=5)
+    rs, tids = [], []
+
+    def client():
+        tids.append(threading.get_ident())
+        rs.extend(eng.submit(f, 1) for f in fs)
+
+    th = threading.Thread(target=client, name="client")
+    th.start()
+    th.join(timeout=60)
+    assert not th.is_alive()
+    eng.drain()
+    assert all(r.done() for r in rs)
+    queue = _xs(eng, "serve.queue")
+    assert sorted(e["args"]["rid"] for e in queue) == \
+        sorted(r.rid for r in rs)
+    assert {e["tid"] for e in queue} == set(tids)
+    admits = _xs(eng, "serve.admit")
+    admitted = [rid for a in admits for rid in a["args"]["rids"]]
+    assert sorted(admitted) == sorted(r.rid for r in rs)
+    for q in queue:
+        a, = [a for a in admits if q["args"]["rid"] in a["args"]["rids"]]
+        assert q["dur"] >= 0 and q["ts"] + q["dur"] <= a["ts"] + 1e-3
+
+
+def test_engine_spans_in_a_profiler_trace(tmp_path):
+    """A short CPU run of ForecastEngine under jax.profiler: the step
+    boundary's spans sit by name on the trace's host plane."""
+    import glob
+    from jax.profiler import ProfileData
+    eng = tiny_engine()
+    eng.warmup()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.serve(_fields(2, eng, seed=6), [1, 2])
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"serve.form", "serve.admit", "serve.step", "serve.peel",
+            "serve.peel.wait", "serve.peel.fetch"} <= names
+
+
 def test_engine_validation():
     eng = tiny_engine()
     with pytest.raises(ValueError, match="fields shape"):

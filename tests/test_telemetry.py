@@ -1,8 +1,9 @@
 """Telemetry subsystem tests (DESIGN.md §14): tracer semantics (span
-nesting/ordering under threads, Chrome trace-event schema, counter and
-gauge behavior under contention), exporter flush on preemption, and the
-MFU / comm-fraction accounting pinned against the Fig. 7 roofline
-numbers for weathermixer-1b."""
+nesting/ordering under threads, explicit-time spans, Chrome trace-event
+schema, counter and gauge behavior under contention), the spans' place
+in a ``jax.profiler`` trace and the backend-compile spans, exporter
+flush on preemption, and the analytic step-cost counts pinned against
+the Fig. 7 roofline numbers for weathermixer-1b."""
 import json
 import math
 import os
@@ -115,6 +116,163 @@ def test_ring_buffer_bounds_events():
     assert [e["args"]["i"] for e in spans] == list(range(40, 50))
 
 
+def test_explicit_span_lands_in_ring_and_both_exports(tmp_path):
+    tr = Tracer()
+    t0 = tr.now_ns()
+    t1 = t0 + 2_500_000                      # 2.5 ms later
+    other, started, done = [], threading.Event(), threading.Event()
+
+    def elsewhere():
+        other.append(threading.get_ident())
+        started.set()
+        done.wait(10)
+
+    th = threading.Thread(target=elsewhere, name="submitter")
+    th.start()
+    assert started.wait(10)
+    with tr.span("outer"):
+        tr.complete("explicit", t0, t1, rid=7)            # this thread
+    tr.complete("queued", t0, t1, tid=other[0], rid=8)    # another track
+    done.set()
+    th.join(timeout=10)
+    assert not th.is_alive()
+    with tr.lock:
+        ring = list(tr._events)
+    ev = {e[1]: e for e in ring}
+    # the same tuple form as span(): (ph, name, ts, dur, tid, depth, args)
+    assert ev["explicit"] == ("X", "explicit", t0 - tr.t0_ns, 2_500_000,
+                              threading.get_ident(), 1, {"rid": 7})
+    assert ev["queued"][4:] == (other[0], 0, {"rid": 8})
+    xs = {e["name"]: e for e in _xs(tr)}
+    assert xs["explicit"]["dur"] == pytest.approx(2500.0)
+    assert xs["explicit"]["args"] == {"rid": 7}
+    meta = {e["tid"]: e["args"]["name"] for e in tr.chrome_events()
+            if e.get("ph") == "M" and e["name"] == "thread_name"}
+    assert meta[other[0]] == "submitter"
+    path = str(tmp_path / "out.trace.json")
+    tr.export_chrome(path)
+    with open(path) as f:
+        names = {e["name"] for e in json.load(f)["traceEvents"]}
+    assert {"explicit", "queued"} <= names
+    tr.export_jsonl(telemetry.jsonl_path_for(path))
+    _, _, spans, *_ = trace_report.split_records(trace_report.load_records(
+        telemetry.jsonl_path_for(path)))
+    assert spans["explicit"] == {"count": 1, "total_s": 0.0025,
+                                 "mean_s": 0.0025}
+    assert spans["queued"]["count"] == 1
+
+
+class _Annotations:
+    """Stand-in annotation factory: records the names it is entered
+    with."""
+
+    def __init__(self):
+        self.entered = []
+
+    def __call__(self, name):
+        import contextlib
+        self.entered.append(name)
+        return contextlib.nullcontext()
+
+
+def test_span_enters_the_annotation_of_its_name():
+    ann = _Annotations()
+    tr = Tracer()
+    tr.annotate = ann
+    with tr.span("outer", step=0):
+        with tr.span("inner"):
+            pass
+    assert ann.entered == ["outer", "inner"]
+    assert {e["name"] for e in _xs(tr)} == {"outer", "inner"}
+
+
+def test_disabled_tracer_records_neither_span_nor_annotation():
+    ann = _Annotations()
+    tr = Tracer(enabled=False)
+    tr.annotate = ann
+    with tr.span("invisible"):
+        pass
+    t = tr.now_ns()
+    tr.complete("also_invisible", t, t + 1)
+    assert ann.entered == [] and _xs(tr) == []
+
+
+def _host_plane_names(trace_dir):
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(ev.name for ev in line.events)
+    return names
+
+
+def _profiled(trace_dir, fn):
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_plane_names(str(trace_dir))
+
+
+def test_spans_show_on_the_profiler_host_plane(tmp_path):
+    """An attached tracer's spans are annotations in a jax.profiler
+    trace, read back by name from its host plane; a disabled one's are
+    not."""
+    on = telemetry.attach_profiler(Tracer())
+    off = telemetry.attach_profiler(Tracer(enabled=False))
+
+    def work():
+        with on.span("repro.test.on"):
+            with on.span("repro.test.on.inner"):
+                pass
+        with off.span("repro.test.off"):
+            pass
+
+    names = _profiled(tmp_path / "prof", work)
+    assert {"repro.test.on", "repro.test.on.inner"} <= names
+    assert "repro.test.off" not in names
+
+
+def test_train_engine_spans_in_a_profiler_trace(tmp_path):
+    """A short CPU run of TrainEngine under jax.profiler: the loop's and
+    the feed's spans sit by name on the trace's host plane."""
+    from repro.launch.engine import EngineConfig, TrainEngine
+    eng = TrainEngine("internlm2-1.8b", config=EngineConfig(
+        steps=2, batch=2, seq_len=16, log_every=1 << 30))
+    names = _profiled(tmp_path / "prof", eng.run)
+    assert {"data_wait", "step", "dispatch", "pipeline.produce",
+            "pipeline.h2d"} <= names
+
+
+def test_fresh_jit_compile_records_one_compile_span():
+    import jax
+    import numpy as np
+    tr = telemetry.attach_profiler(Tracer())
+    telemetry.attach_profiler(tr)               # attaching twice: one sink
+    fn = jax.jit(lambda x: x * 3.0 - 1.0)
+    t0 = tr.now_ns()
+    with tr.span("caller"):
+        fn(np.ones(11, np.float32))
+    t1 = tr.now_ns()
+    fn(np.ones(11, np.float32))                 # cached: no compile
+    comp = _xs(tr, "jax.compile")
+    assert len(comp) == 1
+    c, = comp
+    caller, = _xs(tr, "caller")
+    assert c["tid"] == caller["tid"] == threading.get_ident()
+    assert (t0 - tr.t0_ns) / 1e3 <= c["ts"] + 1.0
+    assert c["ts"] + c["dur"] <= (t1 - tr.t0_ns) / 1e3
+    assert c["dur"] > 0 and "lambda" in c["args"]["fun"]
+
+
 # ---------------------------------------------------------------------------
 # Chrome trace schema
 # ---------------------------------------------------------------------------
@@ -152,8 +310,8 @@ def test_chrome_export_schema(tmp_path):
 def test_jsonl_export_roundtrip(tmp_path):
     tr = Tracer()
     tr.set_meta(arch="x", mesh_model=2)
-    tr.step_record(step=0, dur_s=0.5, mfu=0.5, comm_fraction=0.1,
-                   achieved_tflops=10.0)
+    tr.step_record(step=0, rollout=1, dur_s=0.5, data_wait_s=0.1,
+                   device="cpu")
     with tr.span("step", step=0):
         pass
     tr.counter("c")
@@ -287,7 +445,8 @@ def test_peaks_table_keyed_by_device_kind():
     assert v5e.device == "TPU v5 lite"
     assert v5e.peak_flops == A.PEAKS["TPU v5 lite"].flops_bf16 == 197e12
     assert A.PEAKS["TPU v5 lite"].hbm_bw == 819e9
-    assert "mfu" in v5e.metrics(1.0)
+    assert v5e.t_compute_s == pytest.approx(
+        v5e.flops_per_step / 197e12)
     # an unknown TPU kind never borrows v5e's peaks
     with pytest.raises(ValueError, match="TPU v9 imaginary"):
         telemetry.build_cost_model(cfg, device=_Dev("tpu", "TPU v9 imaginary"))
@@ -295,8 +454,7 @@ def test_peaks_table_keyed_by_device_kind():
     cpu = telemetry.build_cost_model(cfg, device=_Dev("cpu", "cpu"))
     assert cpu.device == "cpu" and cpu.peak_flops is None
     assert cpu.t_compute_s is None
-    m = cpu.metrics(1.0)
-    assert "mfu" not in m and m["achieved_tflops"] > 0
+    assert cpu.flops_per_step == v5e.flops_per_step > 0
 
 
 def test_engine_step_records_name_their_device():
@@ -307,36 +465,35 @@ def test_engine_step_records_name_their_device():
     eng.run()
     rec, = eng.tracer.step_records()
     assert rec["device"] == jax.devices()[0].device_kind
-    if jax.devices()[0].platform != "tpu":
-        assert "mfu" not in rec
+    # measured fields only: nothing derived from the analytic model
+    assert set(rec) == {"step", "rollout", "dur_s", "data_wait_s",
+                        "device"}
+    assert rec["dur_s"] > 0 and rec["data_wait_s"] >= 0
     meta, steps, *_ = trace_report.split_records(
         [dict(eng.tracer._meta, kind="meta"), dict(rec, kind="step")])
     assert trace_report.check(meta, steps) == []
 
 
 def test_cost_model_mfu_8way():
-    """wm-1b on an 8-way model mesh: the accounting identities the step
-    records are built from."""
+    """wm-1b on an 8-way model mesh: the analytic counts stamped into
+    the run's meta header, pinned."""
     cfg = get_config(WM)
     cm = telemetry.build_cost_model(cfg, n_model=8, n_data=1, batch=1)
-    assert cm.n_devices == 8 and cm.flops_per_step > 0
-    assert cm.comm_bytes_per_device > 0 and cm.hops == 7
-    # a step that runs exactly at the compute roofline is MFU 1.0 at
-    # peak TFLOPs by construction
-    m = cm.metrics(cm.t_compute_s)
-    assert m["mfu"] == pytest.approx(1.0)
-    assert m["achieved_tflops"] == pytest.approx(A.PEAK_FLOPS_BF16 / 1e12)
-    # twice the time -> half the MFU; rollout r scales work r-fold
-    assert cm.metrics(2 * cm.t_compute_s)["mfu"] == pytest.approx(0.5)
-    assert cm.metrics(2 * cm.t_compute_s, rollout=2)["mfu"] == \
-        pytest.approx(1.0)
-    # comm_fraction is the modeled collective share, capped at 1
-    t = 10 * cm.t_collective_s
-    assert cm.metrics(t)["comm_fraction"] == pytest.approx(0.1)
-    assert cm.metrics(0.5 * cm.t_collective_s)["comm_fraction"] == 1.0
-    # degenerate timings stay finite
-    z = cm.metrics(0.0)
-    assert z == {"mfu": 0.0, "achieved_tflops": 0.0, "comm_fraction": 0.0}
+    assert cm.n_devices == 8
+    assert cm.flops_per_step == 49_019_107_737_600.0
+    assert cm.comm_bytes_per_device == 2_228_990_400.0
+    assert cm.hops == 7 and cm.bytes_per_hop == 17_690_400.0
+    assert cm.wire_dtype_bytes == 2
+    assert cm.t_compute_s == pytest.approx(0.031103494757360406, rel=1e-12)
+    assert cm.t_compute_s == pytest.approx(
+        cm.flops_per_step / 8 / A.PEAK_FLOPS_BF16, rel=1e-12)
+    assert cm.t_collective_s == pytest.approx(0.044579808, rel=1e-12)
+    assert cm.t_collective_s == pytest.approx(
+        cm.comm_bytes_per_device / A.ICI_BW, rel=1e-12)
+    meta = cm.as_meta()
+    assert meta["t_compute_s"] == cm.t_compute_s
+    assert meta["t_collective_s"] == cm.t_collective_s
+    assert meta["n_devices"] == 8
 
 
 def test_cost_model_comm_matches_fig7_collective_term():
@@ -349,35 +506,55 @@ def test_cost_model_comm_matches_fig7_collective_term():
     assert cm.t_collective_s == pytest.approx(p2["t_coll_s"], rel=1e-12)
 
 
-def test_cost_model_meta_roundtrips_through_report():
+def test_cost_model_meta_roundtrips_through_report(tmp_path):
+    """The meta header and the measured step fields survive the JSONL
+    export and ``trace_report``'s reading, check and rendering."""
     cfg = get_config(WM).reduced()
     cm = telemetry.build_cost_model(cfg, n_model=4, n_data=2, batch=8)
     tr = Tracer()
     tr.set_meta(arch=WM, cost_model=cm.as_meta())
-    for i in range(3):
-        tr.step_record(step=i, rollout=1, dur_s=0.01, data_wait_s=0.001,
-                       **cm.metrics(0.01))
-    meta, steps, *_ = trace_report.split_records(tr.jsonl_records())
+    recs = [dict(step=i, rollout=1, dur_s=0.01 * (i + 1),
+                 data_wait_s=0.001 * i, device="cpu") for i in range(3)]
+    for r in recs:
+        tr.step_record(**r)
+        with tr.span("step", step=r["step"]):
+            pass
+    path = str(tmp_path / "run.trace.jsonl")
+    tr.export_jsonl(path)
+    meta, steps, spans, *_ = trace_report.split_records(
+        trace_report.load_records(path))
+    assert meta["cost_model"] == json.loads(json.dumps(cm.as_meta()))
+    assert [{k: v for k, v in s.items() if k != "kind"}
+            for s in steps] == recs
+    assert spans["step"]["count"] == 3
     assert trace_report.check(meta, steps) == []
-    att = trace_report.attribution(meta, steps)
-    assert att is not None
-    assert att["data"] == pytest.approx(0.1, rel=1e-6)
-    total = att["data"] + att["compute"] + att["collective"] + att["other"]
-    assert 0.0 < total <= 3.0 + 1e-9       # shares are clamped per-term
-    assert "bound" in trace_report.verdict(att)
+    import io
+    buf = io.StringIO()
+    trace_report.render(path, out=buf)
+    text = buf.getvalue()
+    assert "-- steps (3, on cpu) --" in text
+    for name in ("dur_s", "data_wait_s", "step"):
+        assert name in text
+    assert trace_report.main([path, "--check"]) == 0
 
 
 def test_trace_report_check_catches_bad_records():
     assert trace_report.check({}, []) == [
         "no meta header record", "no step records"]
-    bad = [{"step": 0, "dur_s": 0.1, "mfu": float("nan"),
-            "comm_fraction": 0.2, "achieved_tflops": 1.0}]
+    bad = [{"step": 0, "dur_s": float("nan"), "data_wait_s": 0.0},
+           {"step": 1, "dur_s": 0.1, "data_wait_s": float("inf")}]
     fails = trace_report.check({"arch": "x"}, bad)
-    assert any("mfu" in f and "not finite" in f for f in fails)
-    bad2 = [{"step": 1, "dur_s": 0.1, "mfu": 1.5, "comm_fraction": 0.2,
-             "achieved_tflops": 1.0}]
-    assert any("outside" in f
-               for f in trace_report.check({"arch": "x"}, bad2))
+    assert any("step 0: dur_s" in f and "not finite" in f for f in fails)
+    assert any("step 1: data_wait_s" in f and "not finite" in f
+               for f in fails)
+    bad2 = [{"step": 2, "dur_s": -0.1, "data_wait_s": 0.0},
+            {"step": 3, "dur_s": 0.1, "data_wait_s": -1e-3},
+            {"step": 4, "dur_s": 0.1}]
+    fails = trace_report.check({"arch": "x"}, bad2)
+    assert any("step 2: dur_s" in f and "negative" in f for f in fails)
+    assert any("step 3: data_wait_s" in f and "negative" in f
+               for f in fails)
+    assert "step 4: missing data_wait_s" in fails
 
 
 # ---------------------------------------------------------------------------
@@ -460,8 +637,8 @@ def test_serve_engine_latency_histograms():
 def test_telemetry_trace_scenario():
     """The end-to-end acceptance run (subprocess, 16 emulated devices):
     an instrumented 4x2 wm-1b training run produces a Perfetto-valid
-    Chrome trace with nested data-wait/step/ckpt spans, a JSONL whose
-    mfu/comm_fraction match the analytic model within ±5%, and an HLO
+    Chrome trace with nested data-wait/step/ckpt spans and the feed's
+    pipeline.h2d spans, a JSONL of measured step records, and an HLO
     collective-byte cross-check of the wire model."""
     here = os.path.dirname(__file__)
     env = dict(os.environ)
