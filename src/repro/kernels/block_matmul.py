@@ -6,12 +6,13 @@ the kernel-level contribution here is an MXU-shaped GEMM:
 
   y = epilogue(x @ w.T + b)      x: [M, K], w: [N, K], y: [M, N]
 
-TPU adaptation (DESIGN.md): tiles are MXU-aligned (multiples of 128 on
-the matmul dims), the K-loop accumulates into a float32 VMEM scratch
-(HBM -> VMEM -> MXU), and the epilogue (bias add + GELU of the mixer MLP's
-first linear) is fused into the final K-step so the activation never
-round-trips to HBM.  Grid order (M, N, K) keeps the x-tile resident while
-sweeping N.
+TPU adaptation (DESIGN.md): tiles are MXU-aligned (multiples of 128 on N
+and K, of the dtype's sublane on M), the K-loop accumulates into a
+float32 VMEM scratch (HBM -> VMEM -> MXU), and the epilogue (bias add +
+GELU of the mixer MLP's first linear) is fused into the final K-step so
+the activation never round-trips to HBM.  The grid is (M, N, K) with K innermost, so each step
+fetches a new x and w tile: the tile's bm*bn/(bm+bn) sets the FLOP per
+byte, which ``ops.tile_plan`` chooses per GEMM shape.
 
 Validated in interpret mode on CPU against ref.py (the pure-jnp oracle);
 on real TPU hardware the same pallas_call runs compiled.
@@ -27,10 +28,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# Scoped VMEM one kernel may take (v5e has 128 MiB a core; the compiler's
+# default is 16 MiB).  Tiles planned for 48 MiB ran fastest, or within
+# 3.3 % of the fastest, of budgets from 16 to 96 MiB on every GEMM shape
+# of wm-zoo-4t training and wm-1b serving on a v5e (PERF.md).
+VMEM_LIMIT_BYTES = 48 * 2**20
+
+
 def sublane(dtype) -> int:
     """Minimum second-to-last tile dim for ``dtype`` on the TPU (f32 8,
     bf16 16, int8/fp8 32) -- the single source of truth for both the
-    block shrink in ops.block_dims and the legality assert below."""
+    block sizes ops picks (tile_plan, block_dims) and the legality assert
+    below."""
     return {4: 8, 2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 8)
 
 
@@ -59,14 +68,14 @@ def _kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, n_k: int,
 
 
 def block_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
-                 *, block_m: int = 256, block_n: int = 256,
-                 block_k: int = 512, epilogue: str = "none",
+                 *, block_m: int, block_n: int, block_k: int,
+                 epilogue: str = "none",
                  interpret: bool = None) -> jax.Array:
     """y = epilogue(x @ w.T + b).  x: [M, K]; w: [N, K]; b: [N] or None.
 
-    M, N, K must be multiples of the block sizes (ops.py pads).
-    Block sizes default to MXU-aligned (multiples of 128) tiles whose
-    working set (bm*bk + bn*bk + bm*bn*4) fits comfortably in ~16 MB VMEM.
+    M, N, K must be multiples of the block sizes (ops.py pads and picks
+    the sizes: ``ops.tile_plan``, whose ``ops.tile_vmem_bytes`` is this
+    kernel's VMEM footprint).
     """
     m, k = x.shape
     n, k2 = w.shape
@@ -77,8 +86,8 @@ def block_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
     assert m % block_m == 0 and n % block_n == 0 and k % block_k == 0, (
         f"shape ({m},{n},{k}) not divisible by blocks "
         f"({block_m},{block_n},{block_k})")
-    # bf16 tiles need a 16-row sublane (f32: 8); ops.block_dims floors the
-    # block sizes accordingly, so by here block_m is already legal
+    # bf16 tiles need a 16-row sublane (f32: 8); ops floors the block
+    # sizes accordingly, so by here block_m is already legal
     sl = sublane(x.dtype)
     assert block_m % sl == 0 or block_m == m, (
         f"block_m={block_m} below the {jnp.dtype(x.dtype).name} sublane "
@@ -112,5 +121,7 @@ def block_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*args)

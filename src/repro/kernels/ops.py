@@ -3,13 +3,15 @@ custom VJPs).
 
 ``matmul`` is the MXU-tiled GEMM used as Jigsaw's compute engine
 (``JigsawConfig(kernel="pallas")``): f32 VMEM accumulation, bias + GELU /
-SiLU epilogue fused into the final K-step.  Block sizes shrink toward the
-problem size (keeping the sublane/lane alignment floors) so a 16-row GEMM
-does not pad to a 256-row tile.  A custom VJP makes the path trainable:
-the backward GEMMs (dx = dz @ w, dw = dz^T @ x) are themselves routed
-through the same Pallas kernel, and fused epilogues recompute their
-pre-activation with one extra kernel call (flash-attention-style
-recomputation) instead of saving it.
+SiLU epilogue fused into the final K-step.  Its tile is read off each
+GEMM's shape and dtype by ``tile_plan``, a small cost model of the
+kernel, unless the caller passes ``block_m/n/k`` (then ``block_dims``
+shrinks those toward the problem size, as it always has).  A custom VJP
+makes the path trainable: the backward GEMMs (dx = dz @ w, dw = dz^T @ x)
+are themselves routed through the same Pallas kernel, each with a plan
+of its own shape, and fused epilogues recompute their pre-activation
+with one extra kernel call (flash-attention-style recomputation) instead
+of saving it.
 
 ``mixer_mlp`` is the drop-in fused path for the WeatherMixer mixing MLPs:
 two MXU-tiled GEMMs with the GELU fused into the first's epilogue.  The
@@ -23,9 +25,20 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.block_matmul import block_matmul, sublane as _sublane
+from repro.kernels.block_matmul import (VMEM_LIMIT_BYTES, block_matmul,
+                                        sublane as _sublane)
+from repro.telemetry.spans import get_tracer
 
 _ACTS = {"gelu": jax.nn.gelu, "silu": jax.nn.silu}
+
+# ``tile_plan``'s model of one TPU v5e core.  The VMEM budget is the
+# kernel's scoped VMEM less 1 MiB for the compiler's own scratch.
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES - 2**20
+_PEAK_FLOP_S = 197e12           # bf16 on the MXU
+_HBM_BYTE_S = 655e9             # 80 % of the 819 GB/s peak: ridge 300 FLOP/B
+_STEP_S = 0.35e-6               # pipeline overhead of one grid step
+_PAD_SLACK = 0.01               # work a plan may pad beyond the ceilings
+_LANE = 128
 
 
 def _pad_to(a: jax.Array, dim: int, mult: int) -> jax.Array:
@@ -56,13 +69,119 @@ def block_dims(m: int, n: int, k: int, *, block_m: int, block_n: int,
     return bm, bn, bk
 
 
+def tile_vmem_bytes(bm: int, bn: int, bk: int, dtype) -> int:
+    """VMEM a ``block_matmul`` tile holds, as the v5e compiler allocates
+    it: two buffers each of the x, w, bias (a sublane-padded row) and out
+    tiles, a third x tile, the f32 accumulator, and the two f32 [bm, bn]
+    temporaries of a fused epilogue."""
+    item = jnp.dtype(dtype).itemsize
+    return ((3 * bm * bk + 2 * bn * bk + 2 * _sublane(dtype) * bn
+             + 2 * bm * bn) * item + 3 * 4 * bm * bn)
+
+
+def _block_sizes(dim: int, align: int) -> list:
+    """Aligned blocks for a dim already rounded up to ``align``: for each
+    grid count the least block that covers it, kept where the count times
+    the block pads ``dim`` by at most ``_PAD_SLACK``."""
+    sizes = set()
+    for g in range(1, dim // align + 1):
+        b = _round_up(-(-dim // g), align)
+        if -(-dim // b) * b <= dim * (1 + _PAD_SLACK):
+            sizes.add(b)
+    return sorted(sizes)
+
+
+def _tile_seconds(mp: int, np_: int, kp: int, bm: int, bn: int, bk: int,
+                  item: int) -> float:
+    """Modelled time of the block grid: each step the larger of its MXU
+    time and its x and w tiles' HBM time, plus ``_STEP_S``."""
+    steps = -(-mp // bm) * -(-np_ // bn) * -(-kp // bk)
+    return steps * (max(2 * bm * bn * bk / _PEAK_FLOP_S,
+                        (bm + bn) * bk * item / _HBM_BYTE_S) + _STEP_S)
+
+
+def padded_dims(m: int, n: int, k: int, dtype):
+    """The alignment ceilings: m to the dtype's sublane, n and k to the
+    lane (128)."""
+    return _round_up(m, _sublane(dtype)), _round_up(n, _LANE), \
+        _round_up(k, _LANE)
+
+
+def pad_share(m: int, n: int, k: int, bm: int, bn: int, bk: int,
+              dtype) -> float:
+    """Work the tile's block grid pads beyond the alignment ceilings, as a
+    share of the work at the ceilings."""
+    mp, np_, kp = padded_dims(m, n, k, dtype)
+    return (_round_up(mp, bm) * _round_up(np_, bn) * _round_up(kp, bk)
+            / (mp * np_ * kp) - 1.0)
+
+
+def tile_plan(m: int, n: int, k: int, dtype=jnp.bfloat16):
+    """The (bm, bn, bk) tile ``matmul`` runs an [m, k] x [n, k] GEMM with.
+
+    Pure Python on the shape and dtype (no tracing, no device query).
+    Blocks are legal for ``block_matmul`` (bm a multiple of the dtype's
+    sublane, bn and bk of 128, or the whole padded dim), pad at most
+    ``_PAD_SLACK`` of the work beyond the alignment ceilings, and fit
+    ``VMEM_BUDGET_BYTES``; of those the plan takes the one
+    ``_tile_seconds`` prices cheapest.  Large GEMMs thus get tiles of
+    bm*bn/(bm+bn) FLOP per byte above the chip's ridge, and few steps.
+    """
+    item = jnp.dtype(dtype).itemsize
+    mp, np_, kp = padded_dims(m, n, k, dtype)
+    bms = _block_sizes(mp, _sublane(dtype))
+    bns = _block_sizes(np_, _LANE)
+    bks = _block_sizes(kp, _LANE)
+    work_cap = mp * np_ * kp * (1 + _PAD_SLACK)
+    best, best_s = None, float("inf")
+    for bm in bms:
+        if tile_vmem_bytes(bm, bns[0], bks[0], dtype) > VMEM_BUDGET_BYTES:
+            break
+        for bn in bns:
+            if tile_vmem_bytes(bm, bn, bks[0], dtype) > VMEM_BUDGET_BYTES:
+                break
+            for bk in bks:
+                if tile_vmem_bytes(bm, bn, bk, dtype) > VMEM_BUDGET_BYTES:
+                    break
+                if (_round_up(mp, bm) * _round_up(np_, bn)
+                        * _round_up(kp, bk) > work_cap):
+                    continue
+                s = _tile_seconds(mp, np_, kp, bm, bn, bk, item)
+                if s < best_s:
+                    best, best_s = (bm, bn, bk), s
+    return best
+
+
+def gemm_tile(m: int, n: int, k: int, dtype, block_m: Optional[int] = None,
+              block_n: Optional[int] = None,
+              block_k: Optional[int] = None):
+    """The GEMM's tile: ``tile_plan``'s, or the caller's sizes shrunk by
+    ``block_dims``.  Recorded once per trace (that is, per compiled GEMM)
+    as a ``gemm.plan`` event on the process tracer."""
+    given = (block_m, block_n, block_k)
+    override = given != (None, None, None)
+    if override:
+        if None in given:
+            raise ValueError(f"matmul takes all of block_m/n/k or none, "
+                             f"got {given}")
+        tile = block_dims(m, n, k, block_m=block_m, block_n=block_n,
+                          block_k=block_k, dtype=dtype)
+    else:
+        tile = tile_plan(m, n, k, dtype)
+    get_tracer().event("gemm.plan", m=m, n=n, k=k, bm=tile[0], bn=tile[1],
+                       bk=tile[2], pad_share=pad_share(m, n, k, *tile,
+                                                       dtype),
+                       override=override)
+    return tile
+
+
 def _matmul_raw(x, w, b, epilogue, block_m, block_n, block_k, interpret):
-    """Pad/shrink to the block grid, run the kernel, slice back.
+    """Pad to the block grid, run the kernel, slice back.
 
     bf16 inputs run the MXU at its half-width rate with fp32 VMEM
-    accumulation inside the kernel; ``block_dims`` widens the sublane
-    floor to 16 rows for 2-byte dtypes (the TPU tile constraint) so a
-    bf16 GEMM never issues an 8-row tile the hardware cannot form.
+    accumulation inside the kernel; the sublane floor is 16 rows for
+    2-byte dtypes (the TPU tile constraint), so a bf16 GEMM never issues
+    an 8-row tile the hardware cannot form.
     """
     if w.dtype != x.dtype:
         # policy casts happen at the linear-apply boundary; anything that
@@ -72,8 +191,7 @@ def _matmul_raw(x, w, b, epilogue, block_m, block_n, block_k, interpret):
         w = w.astype(x.dtype)
     m, k = x.shape
     n = w.shape[0]
-    bm, bn, bk = block_dims(m, n, k, block_m=block_m, block_n=block_n,
-                            block_k=block_k, dtype=x.dtype)
+    bm, bn, bk = gemm_tile(m, n, k, x.dtype, block_m, block_n, block_k)
     xp = _pad_to(_pad_to(x, 0, bm), 1, bk)
     wp = _pad_to(_pad_to(w, 0, bn), 1, bk)
     bp = _pad_to(b, 0, bn) if b is not None else None
@@ -104,8 +222,8 @@ def _matmul_bwd(epilogue, block_m, block_n, block_k, interpret, res, dy):
         z = _matmul_raw(x, w, b, "none", *blk).astype(jnp.float32)
         _, act_vjp = jax.vjp(_ACTS[epilogue], z)
         dz = act_vjp(dy.astype(jnp.float32))[0].astype(dy.dtype)
-    # Backward GEMMs through the same MXU-tiled kernel:
-    #   dx[m, k] = dz @ w   and   dw[n, k] = dz^T @ x.
+    # Backward GEMMs through the same MXU-tiled kernel, each planned from
+    # its own shape:  dx[m, k] = dz @ w   and   dw[n, k] = dz^T @ x.
     dx = _matmul_raw(dz, w.T, None, "none", *blk).astype(x.dtype)
     dw = _matmul_raw(dz.T, x.T, None, "none", *blk).astype(w.dtype)
     db = jnp.sum(dz, axis=0).astype(b.dtype) if b is not None else None
@@ -118,12 +236,15 @@ _matmul.defvjp(_matmul_fwd, _matmul_bwd)
 @partial(jax.jit, static_argnames=("epilogue", "block_m", "block_n",
                                    "block_k", "interpret"))
 def matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None, *,
-           epilogue: str = "none", block_m: int = 256, block_n: int = 256,
-           block_k: int = 512, interpret: bool = None) -> jax.Array:
+           epilogue: str = "none", block_m: Optional[int] = None,
+           block_n: Optional[int] = None, block_k: Optional[int] = None,
+           interpret: bool = None) -> jax.Array:
     """Padded/blocked y = epilogue(x @ w.T + b) for arbitrary 2-D shapes.
 
-    Differentiable (custom VJP; backward GEMMs also run the Pallas
-    kernel), so it can sit inside the distributed training hot path.
+    The tile is ``tile_plan``'s for the shape unless all of ``block_m/n/k``
+    are given.  Differentiable (custom VJP; backward GEMMs also run the
+    Pallas kernel), so it can sit inside the distributed training hot
+    path.
     """
     return _matmul(x, w, b, epilogue, block_m, block_n, block_k, interpret)
 
@@ -140,8 +261,10 @@ def matmul_nd(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
                                    "interpret"))
 def mixer_mlp(x: jax.Array, w1: jax.Array, b1: Optional[jax.Array],
               w2: jax.Array, b2: Optional[jax.Array], *,
-              block_m: int = 256, block_n: int = 256,
-              block_k: int = 512, interpret: bool = None) -> jax.Array:
+              block_m: Optional[int] = None,
+              block_n: Optional[int] = None,
+              block_k: Optional[int] = None,
+              interpret: bool = None) -> jax.Array:
     """Fused mixer MLP over the last dim: gelu(x @ w1.T + b1) @ w2.T + b2.
 
     x: [..., rows, d_in]; w1: [d_h, d_in]; w2: [d_out, d_h].

@@ -74,13 +74,27 @@ def test_matmul_small_rows_correct():
 # custom VJP: pallas grads == XLA grads
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("epilogue", ["none", "gelu", "silu"])
-@pytest.mark.parametrize("bias", [True, False])
-def test_matmul_grads_match_ref(epilogue, bias):
+# (m, k, n): the ragged shapes run the forward, dx and dw GEMMs each on
+# its own planned tile, one whole-dim block or several of no power of two
+# (atol: the f32 sums of the ragged case run to ~1e3 terms of gradients
+# up to ~3e2, so reduction order alone moves them by ~1e-3)
+_GRAD_MKN = {"small": ((24, 72, 56), 2e-4),
+             "wholedim": ((300, 700, 130), 2e-4),
+             "ragged": ((1100, 2192, 700), 1e-3)}
+_GRAD_CASES = (
+    [pytest.param(bias, epi, "small", id=f"{bias}-{epi}")
+     for bias in (True, False) for epi in ("none", "gelu", "silu")]
+    + [pytest.param(True, epi, shape, id=f"True-{epi}-{shape}")
+       for shape in ("wholedim", "ragged") for epi in ("none", "gelu")])
+
+
+@pytest.mark.parametrize("bias,epilogue,shape", _GRAD_CASES)
+def test_matmul_grads_match_ref(bias, epilogue, shape):
+    (m, k, n), atol = _GRAD_MKN[shape]
     k1, k2, k3 = jax.random.split(KEY, 3)
-    x = jax.random.normal(k1, (24, 72))
-    w = jax.random.normal(k2, (56, 72)) * 0.05
-    b = jax.random.normal(k3, (56,)) * 0.1 if bias else None
+    x = jax.random.normal(k1, (m, k))
+    w = jax.random.normal(k2, (n, k)) * 0.05
+    b = jax.random.normal(k3, (n,)) * 0.1 if bias else None
 
     def f_pallas(*args):
         xx, ww, bb = (args if bias else (*args, None))
@@ -96,7 +110,7 @@ def test_matmul_grads_match_ref(epilogue, bias):
     gr = jax.grad(f_ref, argnums=nums)(*args)
     for a, c in zip(gp, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(c),
-                                   rtol=2e-4, atol=2e-4)
+                                   rtol=2e-4, atol=atol)
 
 
 def test_linear_apply_pallas_vs_xla_fwd_and_grad():

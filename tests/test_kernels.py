@@ -7,6 +7,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
+from repro.telemetry.spans import Tracer, set_tracer
 
 KEY = jax.random.PRNGKey(0)
 
@@ -34,14 +35,85 @@ def test_matmul_shape_sweep(m, k, n, mul, epilogue):
                                atol=2e-5)
 
 
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
-                                       (jnp.bfloat16, 3e-2)])
-def test_matmul_dtypes(dtype, tol):
-    x, w, b = _mk(256, 384, 192, dtype)
-    y = ops.matmul(x, w, b, epilogue="gelu")
-    r = ref.block_matmul_ref(x, w, b, "gelu")
+# (m, k, n) cases beyond the first two are ragged: the plan runs them as
+# one whole-dim block, or as several blocks of no power of two
+@pytest.mark.parametrize("dtype,tol,mkn,epilogue", [
+    pytest.param(jnp.float32, 2e-5, (256, 384, 192), "gelu",
+                 id="float32-2e-05"),
+    pytest.param(jnp.bfloat16, 3e-2, (256, 384, 192), "gelu",
+                 id="bfloat16-0.03"),
+    pytest.param(jnp.float32, 2e-5, (300, 700, 130), "gelu",
+                 id="float32-wholedim-gelu"),
+    pytest.param(jnp.float32, 1e-4, (1100, 2192, 700), "none",
+                 id="float32-ragged-none"),
+    pytest.param(jnp.bfloat16, 3e-2, (1500, 600, 2192), "gelu",
+                 id="bfloat16-ragged-gelu"),
+    pytest.param(jnp.bfloat16, 3e-2, (300, 700, 130), "none",
+                 id="bfloat16-wholedim-none"),
+])
+def test_matmul_dtypes(dtype, tol, mkn, epilogue):
+    x, w, b = _mk(*mkn, dtype)
+    y = ops.matmul(x, w, b, epilogue=epilogue)
+    r = ref.block_matmul_ref(x, w, b, epilogue)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(r, np.float32), rtol=tol, atol=tol)
+
+
+def _fwd_dx_dw(name, m, n, k):
+    """A GEMM's (m, n, k) and those of its input and weight gradients."""
+    return [(f"{name}", m, n, k), (f"{name}-dx", m, k, n),
+            (f"{name}-dw", n, k, m)]
+
+
+_T = 91 * 180                      # tokens of a 728x1440 grid, 8x8 patches
+_PATCH = 8 * 8 * 69
+# wm-zoo-4t (Table 1 model 5: d 2192, d_tok 4320, d_ch 2192) at batch 2:
+# the forward GEMMs of encoder, token and channel MLPs and decoder, each
+# with its dx and dw; then wm-1b (d 4320, d_tok 8640, d_ch 4320) forward
+# GEMMs at serving buckets 1, 2 and 4; then a wm-1b channel GEMM whose
+# output is a d/4 shard.
+_PLAN_SHAPES = (
+    _fwd_dx_dw("zoo-enc", 2 * _T, 2192, _PATCH)
+    + _fwd_dx_dw("zoo-tok1", 2 * 2192, 4320, _T)
+    + _fwd_dx_dw("zoo-tok2", 2 * 2192, _T, 4320)
+    + _fwd_dx_dw("zoo-ch", 2 * _T, 2192, 2192)
+    + _fwd_dx_dw("zoo-dec", 2 * _T, _PATCH, 2192)
+    + [(f"wm1b-{g}-b{b}", b * m, n, k) for b in (1, 2, 4)
+       for g, m, n, k in (("enc", _T, 4320, _PATCH),
+                          ("tok1", 4320, 8640, _T),
+                          ("tok2", 4320, _T, 8640),
+                          ("ch", _T, 4320, 4320),
+                          ("dec", _T, _PATCH, 4320))]
+    + [("wm1b-ch-shard4", _T, 4320 // 4, 4320)])
+
+
+@pytest.mark.parametrize("m,n,k", [s[1:] for s in _PLAN_SHAPES],
+                         ids=[s[0] for s in _PLAN_SHAPES])
+def test_tile_plan(m, n, k):
+    """The plan's tile is legal, fits the VMEM budget, pads at most 1.5 %
+    of the work beyond the alignment ceilings, is compute-bound with
+    margin (>= 300 FLOP/B), and gives way to explicit sizes."""
+    bf = jnp.bfloat16
+    bm, bn, bk = ops.tile_plan(m, n, k, bf)
+    mp, np_, kp = ops.padded_dims(m, n, k, bf)
+    assert bm % 16 == 0 and 16 <= bm <= mp
+    assert bn % 128 == 0 and 128 <= bn <= np_
+    assert bk % 128 == 0 and 128 <= bk <= kp
+    assert ops.tile_vmem_bytes(bm, bn, bk, bf) <= ops.VMEM_BUDGET_BYTES
+    assert 0.0 <= ops.pad_share(m, n, k, bm, bn, bk, bf) <= 0.015
+    assert bm * bn / (bm + bn) >= 300
+    tracer = Tracer()
+    prev = set_tracer(tracer)
+    try:
+        assert ops.gemm_tile(m, n, k, bf) == (bm, bn, bk)
+        assert ops.gemm_tile(m, n, k, bf, 128, 256, 512) == (128, 256, 512)
+    finally:
+        set_tracer(prev)
+    plans = [e["args"] for e in tracer.chrome_events()
+             if e["name"] == "gemm.plan"]
+    assert [(p["bm"], p["bn"], p["bk"], p["override"]) for p in plans] == [
+        (bm, bn, bk, False), (128, 256, 512, True)]
+    assert plans[0]["m"] == m and plans[0]["n"] == n and plans[0]["k"] == k
 
 
 def test_matmul_no_bias():

@@ -55,13 +55,33 @@ def _compiles_as_tpu_kernel(fn, *args) -> None:
     assert "tpu_custom_call" in text
 
 
+PATCH = WM.patch_dim(WM1B)                      # 8 * 8 * 69 = 4,416
+ZOO_D, ZOO_TOK = 2192, 4320                     # wm-zoo-4t (Table 1 model 5)
+
+
 @pytest.mark.parametrize("m,k,n", [
     # token-mixing GEMM: [C, T] @ tok_fc1.w[d_tok, T].T (T padded in-kernel)
     (WM1B.d_model, TOKENS, WM1B.wm_d_tok),
     # channel-mixing GEMM: [T, d] @ ch_fc1.w[d_ch, d].T
     (TOKENS, WM1B.d_model, WM1B.wm_d_ch),
-], ids=["token", "channel"])
+    # wm-1b at serving bucket 4: encoder, both token GEMMs, channel, decoder
+    (4 * TOKENS, PATCH, WM1B.d_model),
+    (4 * WM1B.d_model, TOKENS, WM1B.wm_d_tok),
+    (4 * WM1B.d_model, WM1B.wm_d_tok, TOKENS),
+    (4 * TOKENS, WM1B.d_model, WM1B.wm_d_ch),
+    (4 * TOKENS, WM1B.d_model, PATCH),
+    # wm-zoo-4t at batch 2: token and channel GEMMs, the token GEMM's dx
+    # and the decoder's dw
+    (2 * ZOO_D, TOKENS, ZOO_TOK),
+    (2 * TOKENS, ZOO_D, ZOO_D),
+    (2 * ZOO_D, ZOO_TOK, TOKENS),
+    (PATCH, 2 * TOKENS, ZOO_D),
+], ids=["token", "channel", "b4-encoder", "b4-token1", "b4-token2",
+        "b4-channel", "b4-decoder", "zoo-token", "zoo-channel",
+        "zoo-token-dx", "zoo-decoder-dw"])
 def test_block_matmul_wm1b_gelu_epilogue(one_chip, m, k, n):
+    """Each GEMM on its planned tile, with the gelu epilogue (the most VMEM
+    a tile of that shape takes)."""
     def fn(x, w, b):
         return ops.matmul(x, w, b, epilogue="gelu", interpret=False)
 
