@@ -7,12 +7,21 @@ the kernel-level contribution here is an MXU-shaped GEMM:
   y = epilogue(x @ w.T + b)      x: [M, K], w: [N, K], y: [M, N]
 
 TPU adaptation (DESIGN.md): tiles are MXU-aligned (multiples of 128 on N
-and K, of the dtype's sublane on M), the K-loop accumulates into a
-float32 VMEM scratch (HBM -> VMEM -> MXU), and the epilogue (bias add +
-GELU of the mixer MLP's first linear) is fused into the final K-step so
-the activation never round-trips to HBM.  The grid is (M, N, K) with K innermost, so each step
-fetches a new x and w tile: the tile's bm*bn/(bm+bn) sets the FLOP per
-byte, which ``ops.tile_plan`` chooses per GEMM shape.
+and K, of the dtype's sublane on M, or the whole dim), the K-loop
+accumulates into a float32 VMEM scratch (HBM -> VMEM -> MXU), and the
+epilogue (bias add + GELU of the mixer MLP's first linear) is fused into
+the final K-step so the activation never round-trips to HBM.  The grid
+is (M, N, K) with K innermost, so each step fetches a new x and w tile:
+the tile's bm*bn/(bm+bn) sets the FLOP per byte, which ``ops.tile_plan``
+chooses per GEMM shape.
+
+Operands are read where they lie, unpadded: the grid is the ceiling of
+each dim over its block.  An M or N edge block reads rows past the end
+of x or w, but they only feed output rows and columns past [M, N], whose
+writes Pallas drops.  A K tail cannot be dropped, so the last K step
+zeroes the tail columns of both tiles in VMEM before the dot (a select,
+not a multiply: out-of-bounds VMEM may hold NaN), selecting only in the
+128-lane strip that holds the end of K; every step runs the one dot.
 
 Validated in interpret mode on CPU against ref.py (the pure-jnp oracle);
 on real TPU hardware the same pallas_call runs compiled.
@@ -43,17 +52,40 @@ def sublane(dtype) -> int:
     return {4: 8, 2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 8)
 
 
-def _kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, n_k: int,
-            epilogue: str):
+def _zero_past(ref, k_rem: int) -> None:
+    """Zero a VMEM tile's columns from ``k_rem`` on, in place: a select on
+    the 128-lane strip that holds column ``k_rem``, zeros past it."""
+    lo = k_rem // 128 * 128
+    strip = ref[:, lo:lo + 128]
+    col = jax.lax.broadcasted_iota(jnp.int32, strip.shape, 1)
+    ref[:, lo:lo + 128] = jnp.where(col < k_rem - lo, strip,
+                                    jnp.zeros_like(strip))
+    if lo + 128 < ref.shape[1]:
+        ref[:, lo + 128:] = jnp.zeros((ref.shape[0], ref.shape[1] - lo - 128),
+                                      ref.dtype)
+
+
+def _kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, n_k: int, k_rem: int,
+            widen: bool, epilogue: str):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    if k_rem:
+        # the last K block overhangs K: its columns past k_rem hold stale
+        # VMEM in both tiles, which must not reach the sums
+        @pl.when(k == n_k - 1)
+        def _tail():
+            _zero_past(x_ref, k_rem)
+            _zero_past(w_ref, k_rem)
+
+    x, w = x_ref[...], w_ref[...]
+    if widen:
+        x, w = x.astype(jnp.float32), w.astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _finish():
@@ -73,9 +105,12 @@ def block_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
                  interpret: bool = None) -> jax.Array:
     """y = epilogue(x @ w.T + b).  x: [M, K]; w: [N, K]; b: [N] or None.
 
-    M, N, K must be multiples of the block sizes (ops.py pads and picks
-    the sizes: ``ops.tile_plan``, whose ``ops.tile_vmem_bytes`` is this
-    kernel's VMEM footprint).
+    Any M, N, K: the blocks need not divide them (edge blocks, and a
+    masked last K block; see the module docstring).  Each block is a
+    multiple of its alignment (the dtype's sublane for ``block_m``, 128
+    for ``block_n`` and ``block_k``) or its whole dim.  ``ops.tile_plan``
+    picks the sizes; ``ops.tile_vmem_bytes`` is this kernel's VMEM
+    footprint.
     """
     m, k = x.shape
     n, k2 = w.shape
@@ -83,19 +118,22 @@ def block_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
     assert x.dtype == w.dtype, (
         f"block_matmul needs one operand dtype (got {x.dtype} vs "
         f"{w.dtype}); cast at the linear-apply boundary (ops.py does)")
-    assert m % block_m == 0 and n % block_n == 0 and k % block_k == 0, (
-        f"shape ({m},{n},{k}) not divisible by blocks "
-        f"({block_m},{block_n},{block_k})")
-    # bf16 tiles need a 16-row sublane (f32: 8); ops floors the block
-    # sizes accordingly, so by here block_m is already legal
     sl = sublane(x.dtype)
-    assert block_m % sl == 0 or block_m == m, (
-        f"block_m={block_m} below the {jnp.dtype(x.dtype).name} sublane "
-        f"floor {sl}")
+    for name, blk, dim, align in (("block_m", block_m, m, sl),
+                                  ("block_n", block_n, n, 128),
+                                  ("block_k", block_k, k, 128)):
+        assert blk % align == 0 or blk == dim, (
+            f"{name}={blk} is neither a multiple of {align} "
+            f"({jnp.dtype(x.dtype).name}) nor the whole dim {dim}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    n_k = k // block_k
-    grid = (m // block_m, n // block_n, n_k)
+    n_k = pl.cdiv(k, block_k)
+    grid = (pl.cdiv(m, block_m), pl.cdiv(n, block_n), n_k)
+    # Interpreted on the CPU, whose dot has no bf16 x bf16 -> f32 form for
+    # every operand layout XLA folds into it; a product of two bf16 values
+    # is exact in f32, so widening them first gives the same sums.
+    static = dict(n_k=n_k, k_rem=k % block_k, epilogue=epilogue,
+                  widen=bool(interpret) and x.dtype != jnp.float32)
 
     in_specs = [
         pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
@@ -107,12 +145,12 @@ def block_matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
         # tiling differs from XLA's layout of the [N] operand
         in_specs.append(pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)))
         args.append(b.reshape(1, n))
-        kernel = functools.partial(_kernel, n_k=n_k, epilogue=epilogue)
+        kernel = functools.partial(_kernel, **static)
     else:
         kernel = functools.partial(
             lambda x_ref, w_ref, o_ref, acc_ref, **kw:
             _kernel(x_ref, w_ref, None, o_ref, acc_ref, **kw),
-            n_k=n_k, epilogue=epilogue)
+            **static)
 
     return pl.pallas_call(
         kernel,

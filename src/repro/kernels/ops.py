@@ -1,4 +1,4 @@
-"""jit'd public wrappers around the Pallas kernels (padding + reshapes +
+"""jit'd public wrappers around the Pallas kernels (tile choice, reshapes,
 custom VJPs).
 
 ``matmul`` is the MXU-tiled GEMM used as Jigsaw's compute engine
@@ -15,7 +15,9 @@ of saving it.
 
 ``mixer_mlp`` is the drop-in fused path for the WeatherMixer mixing MLPs:
 two MXU-tiled GEMMs with the GELU fused into the first's epilogue.  The
-wrappers pad every dim up to the block grid and slice the result back.
+kernel reads the operands as they are: a block grid that does not divide
+a dim ends in an edge block (and a masked last K block), so no operand
+is padded and no result sliced.
 """
 from __future__ import annotations
 
@@ -155,9 +157,19 @@ def tile_plan(m: int, n: int, k: int, dtype=jnp.bfloat16):
 def gemm_tile(m: int, n: int, k: int, dtype, block_m: Optional[int] = None,
               block_n: Optional[int] = None,
               block_k: Optional[int] = None):
-    """The GEMM's tile: ``tile_plan``'s, or the caller's sizes shrunk by
-    ``block_dims``.  Recorded once per trace (that is, per compiled GEMM)
-    as a ``gemm.plan`` event on the process tracer."""
+    """The blocks ``block_matmul`` runs the GEMM with: ``tile_plan``'s
+    tile, or the caller's sizes shrunk by ``block_dims``, with a K block
+    past K cut to the whole of K (one K step, nothing to mask).  An M or
+    N block past its dim stays as it is: an edge block needs no mask and
+    does the same MXU work, and a lane-wide N block keeps each column's
+    sum in the same order on the CPU interpreter whether a GEMM runs
+    whole or by column chunks (as the Jigsaw rings' parity needs).
+
+    Recorded once per trace (that is, per compiled GEMM) as a
+    ``gemm.plan`` event on the process tracer: the shape, the blocks, the
+    plan's ``pad_share``, ``override``, and where the grid does not divide
+    the shape, ``edge`` (``"m"``, ``"n"``, ``"mn"`` or ``""``) and
+    ``k_masked``."""
     given = (block_m, block_n, block_k)
     override = given != (None, None, None)
     if override:
@@ -168,15 +180,17 @@ def gemm_tile(m: int, n: int, k: int, dtype, block_m: Optional[int] = None,
                           block_k=block_k, dtype=dtype)
     else:
         tile = tile_plan(m, n, k, dtype)
-    get_tracer().event("gemm.plan", m=m, n=n, k=k, bm=tile[0], bn=tile[1],
-                       bk=tile[2], pad_share=pad_share(m, n, k, *tile,
-                                                       dtype),
-                       override=override)
-    return tile
+    share = pad_share(m, n, k, *tile, dtype)
+    bm, bn, bk = tile[0], tile[1], min(tile[2], k)
+    edge = "m" * (m % bm != 0) + "n" * (n % bn != 0)
+    get_tracer().event("gemm.plan", m=m, n=n, k=k, bm=bm, bn=bn, bk=bk,
+                       pad_share=share, override=override, edge=edge,
+                       k_masked=k % bk != 0)
+    return bm, bn, bk
 
 
 def _matmul_raw(x, w, b, epilogue, block_m, block_n, block_k, interpret):
-    """Pad to the block grid, run the kernel, slice back.
+    """Run the kernel on the operands as they are (no pad, no slice).
 
     bf16 inputs run the MXU at its half-width rate with fp32 VMEM
     accumulation inside the kernel; the sublane floor is 16 rows for
@@ -192,12 +206,13 @@ def _matmul_raw(x, w, b, epilogue, block_m, block_n, block_k, interpret):
     m, k = x.shape
     n = w.shape[0]
     bm, bn, bk = gemm_tile(m, n, k, x.dtype, block_m, block_n, block_k)
-    xp = _pad_to(_pad_to(x, 0, bm), 1, bk)
-    wp = _pad_to(_pad_to(w, 0, bn), 1, bk)
-    bp = _pad_to(b, 0, bn) if b is not None else None
-    y = block_matmul(xp, wp, bp, block_m=bm, block_n=bn, block_k=bk,
+    y = block_matmul(x, w, b, block_m=bm, block_n=bn, block_k=bk,
                      epilogue=epilogue, interpret=interpret)
-    return y[:m, :n]
+    # XLA would fuse a consumer's dynamic-update-slice (a scan stacking
+    # this result, as its transpose stacks each layer's dw) into the kernel
+    # and compile that fusion under the default 16 MiB of scoped VMEM, not
+    # the kernel's VMEM_LIMIT_BYTES: the barrier keeps the kernel whole.
+    return jax.lax.optimization_barrier(y)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -239,7 +254,7 @@ def matmul(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None, *,
            epilogue: str = "none", block_m: Optional[int] = None,
            block_n: Optional[int] = None, block_k: Optional[int] = None,
            interpret: bool = None) -> jax.Array:
-    """Padded/blocked y = epilogue(x @ w.T + b) for arbitrary 2-D shapes.
+    """Blocked y = epilogue(x @ w.T + b) for arbitrary 2-D shapes.
 
     The tile is ``tile_plan``'s for the shape unless all of ``block_m/n/k``
     are given.  Differentiable (custom VJP; backward GEMMs also run the

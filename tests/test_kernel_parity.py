@@ -76,16 +76,20 @@ def test_matmul_small_rows_correct():
 
 # (m, k, n): the ragged shapes run the forward, dx and dw GEMMs each on
 # its own planned tile, one whole-dim block or several of no power of two
-# (atol: the f32 sums of the ragged case run to ~1e3 terms of gradients
-# up to ~3e2, so reduction order alone moves them by ~1e-3)
+# with edge blocks and a masked K tail ("ktail": the forward's last K
+# block holds 112 of 128 columns, the dw's 8).  atol: the f32 sums of the
+# ragged cases run to ~1e3 terms of gradients up to ~1e3, so reduction
+# order alone moves them by ~1e-3.
 _GRAD_MKN = {"small": ((24, 72, 56), 2e-4),
              "wholedim": ((300, 700, 130), 2e-4),
-             "ragged": ((1100, 2192, 700), 1e-3)}
+             "ragged": ((1100, 2192, 700), 1e-3),
+             "ktail": ((520, 6000, 200), 1e-3)}
 _GRAD_CASES = (
     [pytest.param(bias, epi, "small", id=f"{bias}-{epi}")
      for bias in (True, False) for epi in ("none", "gelu", "silu")]
     + [pytest.param(True, epi, shape, id=f"True-{epi}-{shape}")
-       for shape in ("wholedim", "ragged") for epi in ("none", "gelu")])
+       for shape in ("wholedim", "ragged", "ktail")
+       for epi in ("none", "gelu")])
 
 
 @pytest.mark.parametrize("bias,epilogue,shape", _GRAD_CASES)

@@ -5,8 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ops, ref
+from repro.kernels.block_matmul import block_matmul
 from repro.telemetry.spans import Tracer, set_tracer
 
 KEY = jax.random.PRNGKey(0)
@@ -102,18 +104,22 @@ def test_tile_plan(m, n, k):
     assert ops.tile_vmem_bytes(bm, bn, bk, bf) <= ops.VMEM_BUDGET_BYTES
     assert 0.0 <= ops.pad_share(m, n, k, bm, bn, bk, bf) <= 0.015
     assert bm * bn / (bm + bn) >= 300
+    # a K block past K runs as the whole of K
+    run = (bm, bn, min(bk, k))
     tracer = Tracer()
     prev = set_tracer(tracer)
     try:
-        assert ops.gemm_tile(m, n, k, bf) == (bm, bn, bk)
+        assert ops.gemm_tile(m, n, k, bf) == run
         assert ops.gemm_tile(m, n, k, bf, 128, 256, 512) == (128, 256, 512)
     finally:
         set_tracer(prev)
     plans = [e["args"] for e in tracer.chrome_events()
              if e["name"] == "gemm.plan"]
     assert [(p["bm"], p["bn"], p["bk"], p["override"]) for p in plans] == [
-        (bm, bn, bk, False), (128, 256, 512, True)]
+        (*run, False), (128, 256, 512, True)]
     assert plans[0]["m"] == m and plans[0]["n"] == n and plans[0]["k"] == k
+    assert plans[0]["edge"] == "m" * (m % run[0] > 0) + "n" * (n % run[1] > 0)
+    assert plans[0]["k_masked"] == (k % run[2] > 0)
 
 
 def test_matmul_no_bias():
@@ -124,13 +130,97 @@ def test_matmul_no_bias():
 
 
 def test_matmul_unaligned_padding():
-    """Wrapper pads ragged dims and slices back."""
+    """Ragged dims run unpadded: one whole-dim block each, no pad, no
+    slice, and the result has the operands' shape."""
     x, w, b = _mk(300, 700, 130, jnp.float32, seed=3)
     y = ops.matmul(x, w, b)
     r = ref.block_matmul_ref(x, w, b)
     assert y.shape == (300, 130)
     np.testing.assert_allclose(np.asarray(y), np.asarray(r), rtol=2e-5,
                                atol=2e-5)
+
+
+# The TPU interpreter with every out-of-bounds read NaN, as stale VMEM may
+# be on the chip: an M or N edge block's stray rows must stay out of the
+# result, and an unmasked K tail would make the whole of it NaN.  The K
+# tails end in the last block's first 128 lanes ("k-tail-wide" with whole
+# lanes of the block past them) or in a later strip ("mnk-edge").
+_NAN_OOB = pltpu.InterpretParams(out_of_bounds_reads="uninitialized",
+                                 uninitialized_memory="nan")
+# (m, n, k), (bm, bn, bk)
+_EDGE_SHAPES = {
+    "m-edge": ((200, 128, 256), (64, 128, 128)),
+    "n-edge": ((64, 300, 256), (64, 128, 128)),
+    "k-tail": ((64, 128, 300), (64, 128, 128)),
+    "k-tail-wide": ((64, 128, 300), (64, 128, 256)),
+    "mnk-edge": ((200, 300, 400), (64, 128, 256)),
+    "k-whole": ((72, 200, 330), (32, 128, 330)),
+}
+
+
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "nobias"])
+@pytest.mark.parametrize("epilogue", ["none", "gelu", "silu"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", list(_EDGE_SHAPES))
+def test_block_matmul_edge_blocks(shape, dtype, tol, epilogue, bias):
+    (m, n, k), (bm, bn, bk) = _EDGE_SHAPES[shape]
+    x, w, b = _mk(m, k, n, dtype, seed=5)
+    b = b if bias else None
+    y = block_matmul(x, w, b, block_m=bm, block_n=bn, block_k=bk,
+                     epilogue=epilogue, interpret=_NAN_OOB)
+    r = ref.block_matmul_ref(x, w, b, epilogue)
+    assert y.shape == (m, n)
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(r, np.float32), rtol=tol, atol=tol)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+# wm-1b forward GEMMs at bucket 1 (m, n, k), the tile the plan gives them,
+# and what of it overhangs the shape
+_WM1B_B1 = {
+    "enc": ((_T, 4320, _PATCH), (1024, 2176, 896), "mn", True),
+    "tok2": ((4320, _T, 8640), (720, 1024, 4352), "n", True),
+}
+
+
+@pytest.mark.parametrize("gemm", list(_WM1B_B1))
+def test_matmul_wm1b_runs_unpadded(gemm):
+    """At wm-1b's bf16 shapes ``ops.matmul`` is one ``pallas_call`` over
+    the operands as they are: a ceiling-division grid, no ``pad`` and no
+    ``slice``, and a ``gemm.plan`` event that names the edges."""
+    (m, n, k), tile, edge, k_masked = _WM1B_B1[gemm]
+    sds = jax.ShapeDtypeStruct
+    tracer = Tracer()
+    prev = set_tracer(tracer)
+    try:
+        jaxpr = jax.make_jaxpr(ops.matmul)(
+            sds((m, k), jnp.bfloat16), sds((n, k), jnp.bfloat16),
+            sds((n,), jnp.bfloat16))
+    finally:
+        set_tracer(prev)
+    prims = [e.primitive.name for e in _eqns(jaxpr.jaxpr)]
+    assert "pad" not in prims and "slice" not in prims, prims
+    calls = [e for e in _eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert calls[0].params["grid_mapping"].grid == tuple(
+        -(-d // b) for d, b in zip((m, n, k), tile))
+    plans = [e["args"] for e in tracer.chrome_events()
+             if e["name"] == "gemm.plan"]
+    assert [(p["bm"], p["bn"], p["bk"], p["edge"], p["k_masked"])
+            for p in plans] == [(*tile, edge, k_masked)]
 
 
 @settings(max_examples=8, deadline=None)

@@ -14,6 +14,7 @@ library may be loaded by one process at a time, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -50,9 +51,10 @@ def _sds(shape, sharding, dtype=BF16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compiles_as_tpu_kernel(fn, *args) -> None:
+def _compiles_as_tpu_kernel(fn, *args) -> str:
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 PATCH = WM.patch_dim(WM1B)                      # 8 * 8 * 69 = 4,416
@@ -60,7 +62,8 @@ ZOO_D, ZOO_TOK = 2192, 4320                     # wm-zoo-4t (Table 1 model 5)
 
 
 @pytest.mark.parametrize("m,k,n", [
-    # token-mixing GEMM: [C, T] @ tok_fc1.w[d_tok, T].T (T padded in-kernel)
+    # token-mixing GEMM: [C, T] @ tok_fc1.w[d_tok, T].T (T's last K block
+    # masked in-kernel)
     (WM1B.d_model, TOKENS, WM1B.wm_d_tok),
     # channel-mixing GEMM: [T, d] @ ch_fc1.w[d_ch, d].T
     (TOKENS, WM1B.d_model, WM1B.wm_d_ch),
@@ -76,17 +79,50 @@ ZOO_D, ZOO_TOK = 2192, 4320                     # wm-zoo-4t (Table 1 model 5)
     (2 * TOKENS, ZOO_D, ZOO_D),
     (2 * ZOO_D, ZOO_TOK, TOKENS),
     (PATCH, 2 * TOKENS, ZOO_D),
+    # wm-1b decoder at bucket 1: one K block of the whole 4,320
+    (TOKENS, WM1B.d_model, PATCH),
 ], ids=["token", "channel", "b4-encoder", "b4-token1", "b4-token2",
         "b4-channel", "b4-decoder", "zoo-token", "zoo-channel",
-        "zoo-token-dx", "zoo-decoder-dw"])
+        "zoo-token-dx", "zoo-decoder-dw", "b1-decoder"])
 def test_block_matmul_wm1b_gelu_epilogue(one_chip, m, k, n):
     """Each GEMM on its planned tile, with the gelu epilogue (the most VMEM
-    a tile of that shape takes)."""
+    a tile of that shape takes), on its operands as they are: no ``pad``
+    in the compiled program."""
     def fn(x, w, b):
         return ops.matmul(x, w, b, epilogue="gelu", interpret=False)
 
-    _compiles_as_tpu_kernel(fn, _sds((m, k), one_chip), _sds((n, k), one_chip),
-                   _sds((n,), one_chip))
+    text = _compiles_as_tpu_kernel(fn, _sds((m, k), one_chip),
+                                   _sds((n, k), one_chip),
+                                   _sds((n,), one_chip))
+    assert not re.search(r"\bpad\(", text)
+
+
+def test_block_matmul_k_tail_past_strip(one_chip):
+    """Caller-set blocks whose last K block ends 212 columns past K: the
+    kernel selects in one 128-lane strip and zeroes the lanes past it."""
+    def fn(x, w, b):
+        return ops.matmul(x, w, b, epilogue="gelu", block_m=256,
+                          block_n=256, block_k=256, interpret=False)
+
+    _compiles_as_tpu_kernel(fn, _sds((512, 300), one_chip),
+                            _sds((384, 300), one_chip),
+                            _sds((384,), one_chip))
+
+
+def test_matmul_dw_stacked_by_scan(one_chip):
+    """The gradient of a scan over three stacked [2192, 2192] weights (the
+    zoo's channel GEMMs): the scan's transpose stacks each dw with a
+    dynamic-update-slice, which must not fuse into the kernel (XLA would
+    compile that fusion under 16 MiB of scoped VMEM, below the tile's)."""
+    def loss(ws, x):
+        def body(h, w):
+            return ops.matmul(h, w, None, interpret=False), None
+        h, _ = jax.lax.scan(body, x, ws)
+        return jnp.sum(h.astype(jnp.float32))
+
+    _compiles_as_tpu_kernel(jax.grad(loss),
+                            _sds((3, ZOO_D, ZOO_D), one_chip),
+                            _sds((4096, ZOO_D), one_chip))
 
 
 def test_mixer_mlp_wm1b_channel(one_chip):
