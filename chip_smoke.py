@@ -16,7 +16,6 @@ goes through the code ``python -m repro.launch.train`` and
           compiled step holds the Pallas GEMMs as ``tpu_custom_call``;
   kernel  one batch evaluated with ``kernel="pallas"`` and with
           ``kernel="xla"``: the losses agree within ``KERNEL_BAND``;
-  guards  which schedule the fused-ring / fused-Cannon VMEM guards chose;
   serve   ``ForecastEngine`` (bf16, buckets 1 and 2): warmup, four
           requests at leads 1 and 2, drain; every forecast finite and
           728x1440x69, zero compiles after warmup.
@@ -173,18 +172,6 @@ def phase_kernel_check(eng, batch):
         fail(f"pallas vs xla loss differ by {rel} > {KERNEL_BAND}")
 
 
-def phase_guards(tracer, tag: str = "guards") -> None:
-    """Which path each fused-kernel VMEM guard chose while the engine's
-    programs were traced (counted on its tracer by kernels/fused_ring)."""
-    counts = tracer.counters()
-    for guard in ("ring", "cannon"):
-        pre = f"fused_ring.{guard}."
-        chosen = {k[len(pre):]: int(v) for k, v in counts.items()
-                  if k.startswith(pre)}
-        counts[guard] = chosen or "not consulted on this path"
-    say(tag, fused_ring=counts["ring"], fused_cannon=counts["cannon"])
-
-
 def phase_serve(reduced: bool = False):
     """The serve CLI's own function: 4 requests, leads 1 and 2."""
     import numpy as np
@@ -250,7 +237,6 @@ def phase_scheme(scheme: str, reduced: bool = False, steps: int = 2):
         hlo = compiled(eng.step_fns[1], eng.params, eng.opt_state,
                        batch).as_text()
     coll = collective_counts(hlo)
-    phase_guards(eng.tracer, f"guards-{scheme}")
     say(f"scheme-{scheme}", mesh=dict(eng.mesh.shape), losses=losses,
         first_step_s=round(marks[0] - t0, 3),
         steady_step_s=[round(b - a, 3) for a, b in zip(marks, marks[1:])],
@@ -280,7 +266,6 @@ def run_four_chips(reduced: bool = False) -> None:
 def run_one_chip(reduced: bool = False) -> None:
     eng, batch = phase_train(reduced)
     phase_kernel_check(eng, batch)
-    phase_guards(eng.tracer)
     # the training state (~8 GB) leaves the chip before serving loads
     del eng, batch
     gc.collect()

@@ -117,8 +117,8 @@ class AsyncCheckpointWriter:
 
         Returns the Snapshot (its ``bytes_per_rank`` is the per-rank
         byte accounting asserted by the dist scenarios).  ``block=True``
-        degrades to a synchronous save (the A/B baseline the ckpt_io
-        benchmark measures against).
+        degrades to a synchronous save (the engine with
+        ``async_save=False``).
 
         ``prune`` lists older checkpoint directories to delete (the
         engine's keep-last-k GC) -- removed only AFTER this save's files

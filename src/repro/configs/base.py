@@ -75,8 +75,8 @@ class ModelConfig:
                                            # legacy dtypes above, fp32 accum
     # --- parallelism defaults (overridable from the launcher) ---
     scheme: str = "1d"                     # jigsaw scheme: 1d|2d|none
-    impl: str = "rs"                       # 1d impl: ring|ring_chunked|
-                                           #   ring_fused|rs|gspmd|allreduce
+    impl: str = "rs"                       # 1d impl: one of
+                                           #   core.jigsaw.Impl1D
     kernel: str = "xla"                    # local GEMM engine: xla|pallas
     shard_params_over_data: bool = False   # FSDP-hybrid for >~25B params
     remat: bool = True

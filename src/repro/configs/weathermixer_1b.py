@@ -4,7 +4,8 @@ The 1-billion-parameter configuration from §6.2.1: 3 MLP-Mixing blocks,
 d_emb = 4320, d_tok = 8640, d_ch = 4320, on 0.25-degree ERA5
 (721x1440 grid, padded to 728x1440 for 8x8 patching; 69 variables:
 4 surface + 5x13 pressure levels).  Table 1 gives the scaling zoo; see
-``weathermixer_zoo`` below (used by the scaling benchmarks).
+``ZOO`` below (dims and parameter counts pinned by
+tests/test_weathermixer.py).
 """
 from repro.configs.base import ModelConfig
 

@@ -4,15 +4,15 @@ Everything in this framework is a pure function over parameter pytrees
 (nested dicts of jax.Arrays).  ``JigsawConfig`` selects how each linear
 layer completes its distributed contraction:
 
-  scheme="1d", impl in {"ring","ring_chunked","rs","gspmd","allreduce"}
-                                                (paper 2-way, n-way)
+  scheme="1d", impl in jigsaw.Impl1D            (paper 2-way, n-way)
   scheme="2d"                                   (paper 4-way, Cannon)
 
 ``impl="rs"`` (psum_scatter) is the default production path;
 ``"ring_chunked"`` is the paper's own schedule (one output-chunk GEMM
 issued before each hop so send overlaps compute); ``"ring"`` is the
 monolithic-GEMM approximation of it; ``"gspmd"`` lets XLA derive the
-collectives from sharding constraints alone (beyond-paper comparison).
+collectives from sharding constraints alone (beyond-paper comparison);
+``"allreduce"`` is the Megatron-style psum-then-slice baseline.
 
 ``kernel`` selects the compute engine of every local GEMM: ``"xla"``
 (dot_general) or ``"pallas"`` (the MXU-tiled blocked kernel,

@@ -15,7 +15,7 @@ TPU/JAX adaptation (see DESIGN.md §2):
   sharded along its last dim -- the same layout as the input, so layers
   compose without any re-sharding and no weight is ever allgathered.
 
-  Four interchangeable implementations:
+  Five interchangeable implementations:
     - ``ring``  : explicit ppermute ring of partial-sum chunks.  The whole
                   local partial product is computed up-front with ONE GEMM
                   and the ring then only moves chunks of it -- an
@@ -29,18 +29,14 @@ TPU/JAX adaptation (see DESIGN.md §2):
                   hop's send overlaps the next chunk's compute", §4).
                   GEMMs and hops are still separate HLOs: overlap is
                   XLA-best-effort.
-    - ``ring_fused`` : the same schedule as ONE pallas_call per ring
-                  (kernels/fused_ring.py): remote-DMA hops issued from
-                  inside the kernel while the next chunk's MXU GEMM runs
-                  -- overlap guaranteed by construction, not by the
-                  scheduler.  Deterministic chunk-granular fallback off
-                  TPU; bit-identical to ``ring`` (fwd + grads) under
-                  every precision policy.
     - ``rs``    : ``jax.lax.psum_scatter`` -- XLA's native reduce-scatter,
                   which lowers to the same ring on the ICI torus but lets
                   the compiler schedule the overlap.
     - ``gspmd`` : no explicit collectives; sharding constraints only.  XLA
                   GSPMD derives the schedule.  (beyond-paper comparison)
+    - ``allreduce`` : Megatron-style completion, a full ``psum`` and then
+                  a slice of the local chunk: twice the bytes of the
+                  reduce-scatter.  (comparison baseline)
 
   The local GEMMs route through either XLA's dot_general or the MXU-tiled
   Pallas kernel (``kernel="pallas"``, kernels/block_matmul.py): f32 VMEM
@@ -59,9 +55,8 @@ reduce-scatter is a ring allgather, which reproduces the paper's
 from __future__ import annotations
 
 import dataclasses
-import string
 from functools import partial
-from typing import Optional, Sequence, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -70,7 +65,7 @@ from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
 from repro.core.sharding import ShardingRules, constrain
 
-Impl1D = ("ring", "ring_chunked", "ring_fused", "rs", "gspmd", "allreduce")
+Impl1D = ("ring", "ring_chunked", "rs", "gspmd", "allreduce")
 Kernels = ("xla", "pallas")
 
 
@@ -223,25 +218,12 @@ def ring_matmul_chunked(x: jax.Array, w: jax.Array, *, axis_name: str,
 def jigsaw_matmul_1d(x: jax.Array, w: jax.Array, *, axis_name: str,
                      axis_size: int, impl: str = "rs",
                      accum_dtype: Optional[jnp.dtype] = jnp.float32,
-                     kernel: str = "xla",
-                     mesh_axes: Optional[Tuple[str, ...]] = None
-                     ) -> jax.Array:
+                     kernel: str = "xla") -> jax.Array:
     """Manual (inside-shard_map) 1-D Jigsaw matmul.
 
     x: local [..., d/p] block; w: local [m, d/p] block.
     Returns the local [..., m/p] block of ``X @ W.T``.
-    ``mesh_axes`` (mesh axis names, mesh order) is only consumed by the
-    ``ring_fused`` TPU kernel to address its ring neighbours.
     """
-    if impl == "ring_fused":
-        # One pallas_call per ring (kernels/fused_ring.py): the fused-hop
-        # schedule with in-kernel RDMA on TPU, chunk-granular fallback
-        # elsewhere.  Lazy import keeps core -> kernels one-way and cheap.
-        from repro.kernels import fused_ring
-        return fused_ring.fused_ring_matmul(
-            x, w, axis_name=axis_name, axis_size=axis_size,
-            accum_dtype=accum_dtype, kernel=kernel,
-            mesh_axes=mesh_axes).astype(x.dtype)
     if impl == "ring_chunked":
         return ring_matmul_chunked(
             x, w, axis_name=axis_name, axis_size=axis_size,
@@ -401,10 +383,7 @@ def jigsaw_linear(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
             wl = jax.lax.all_gather(wl, fsdp_axis, axis=0, tiled=True)
         return jigsaw_matmul_1d(xl, wl, axis_name=tp, axis_size=p,
                                 impl=impl, accum_dtype=accum_dtype,
-                                kernel=kernel,
-                                mesh_axes=(tuple(mesh.axis_names)
-                                           if set(mesh.axis_names) <= manual
-                                           else None))
+                                kernel=kernel)
 
     # check_vma=False: with B=1 (long_500k) the batch stays replicated
     # and VMA inference cannot see through the FSDP all_gather; the
@@ -540,9 +519,7 @@ def jigsaw_linear_2d(x: jax.Array, w: jax.Array,
 def jigsaw_matmul_2d_t(x: jax.Array, w: jax.Array, *, dom_axis: str,
                        tp_axis: str, dom_size: int, tp_size: int,
                        accum_dtype: Optional[jnp.dtype] = jnp.float32,
-                       kernel: str = "xla",
-                       mesh_axes: Optional[Tuple[str, ...]] = None
-                       ) -> jax.Array:
+                       kernel: str = "xla") -> jax.Array:
     """Manual 2-D Jigsaw *transposed* matmul: ``Y = W @ X`` contracting
     X's second-to-last dim.  This is the paper's "transposed MLP" trick
     (§5: implement ``X^T W`` directly instead of transposing) used by the
@@ -559,10 +536,8 @@ def jigsaw_matmul_2d_t(x: jax.Array, w: jax.Array, *, dom_axis: str,
     q multiply-accumulate steps rotating W left / X up.
 
     ``kernel="pallas"`` lowers each multiply-accumulate step to the fused
-    ``acc + w @ x`` MXU kernel (kernels/fused_ring.cannon_t_step; one
-    pallas_call per step, f32 VMEM accumulation) -- and, on TPU within
-    the VMEM budget, fuses the whole q-step loop into ONE pallas_call
-    with the rotate hops as in-kernel remote copies.
+    ``acc + w @ x`` MXU kernel (kernels/cannon.cannon_t_step; one
+    pallas_call per step, f32 VMEM accumulation).
     """
     if dom_size != tp_size:
         raise ValueError(f"2-D Jigsaw needs a square grid, got "
@@ -572,12 +547,12 @@ def jigsaw_matmul_2d_t(x: jax.Array, w: jax.Array, *, dom_axis: str,
     j = jax.lax.axis_index(tp_axis)
 
     if kernel == "pallas":
-        from repro.kernels import fused_ring
+        from repro.kernels import cannon
         wl = _skew(w, i, tp_axis, q)    # W(i, (j+i) % q)
         xl = _skew(x, j, dom_axis, q)   # X((i+j) % q, j)
-        return fused_ring.fused_cannon_t(
-            wl, xl, dom_axis=dom_axis, tp_axis=tp_axis, q=q,
-            accum_dtype=accum_dtype, mesh_axes=mesh_axes)
+        return cannon.cannon_t_loop(wl, xl, dom_axis=dom_axis,
+                                    tp_axis=tp_axis, q=q,
+                                    accum_dtype=accum_dtype)
 
     def mm(wb, xb):
         # wb: [m_l, t_l]; xb: [..., t_l, c_l] -> [..., m_l, c_l]
@@ -614,8 +589,8 @@ def jigsaw_linear_2d_t(x: jax.Array, w: jax.Array,
       y: [..., m, c]  m on ``mdom``, c on ``mtp``  -- same as x: composable.
 
     ``kernel="pallas"``: the Cannon multiply-accumulate steps run the
-    fused ``acc + w @ x`` MXU kernel (one pallas_call per step; the whole
-    loop when the TPU fused variant applies) instead of dot_general.
+    fused ``acc + w @ x`` MXU kernel (one pallas_call per step) instead
+    of dot_general.
     """
     if not rules.is_2d:
         raise ValueError("jigsaw_linear_2d_t requires 2-D ShardingRules")
@@ -643,9 +618,7 @@ def jigsaw_linear_2d_t(x: jax.Array, w: jax.Array,
     manual = {dom, tp} | set(batch_axes)
 
     fn = partial(jigsaw_matmul_2d_t, dom_axis=dom, tp_axis=tp, dom_size=p,
-                 tp_size=q, accum_dtype=accum_dtype, kernel=kernel,
-                 mesh_axes=(tuple(mesh.axis_names)
-                            if set(mesh.axis_names) <= manual else None))
+                 tp_size=q, accum_dtype=accum_dtype, kernel=kernel)
     y = shard_map(fn, mesh=mesh, in_specs=(xspec, wspec),
                       out_specs=ospec, axis_names=manual,
                       check_vma=False)(x, w)
@@ -656,7 +629,7 @@ def jigsaw_linear_2d_t(x: jax.Array, w: jax.Array,
 
 
 # --------------------------------------------------------------------------
-# Analytic communication volume (for benchmarks / EXPERIMENTS §Paper-claims)
+# Analytic communication volume (telemetry/accounting.py's cost model)
 # --------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -669,12 +642,6 @@ def comm_volume_jigsaw_1d(tokens: int, m: int, p: int, dtype_bytes: int = 2
                           ) -> CommVolume:
     # ring reduce-scatter of [tokens, m]: (p-1) chunks of tokens*m/p each.
     return CommVolume("jigsaw-1d", (p - 1) / p * tokens * m * dtype_bytes)
-
-def comm_volume_megatron_pair(tokens: int, d: int, p: int,
-                              dtype_bytes: int = 2) -> CommVolume:
-    # Megatron fuses two linears around one allreduce of [tokens, d]:
-    # ring allreduce = 2 (p-1)/p * bytes.
-    return CommVolume("megatron-pair", 2 * (p - 1) / p * tokens * d * dtype_bytes)
 
 @dataclasses.dataclass(frozen=True)
 class CommSchedule:
@@ -692,43 +659,26 @@ class CommSchedule:
     flops_per_hop: float
     bytes_per_device: float
 
-    def overlap_ratio(self, ici_bw: float, peak_flops: float) -> float:
-        """compute-time / comm-time per hop (>= 1: the hop is hidden)."""
-        if self.bytes_per_hop == 0:
-            return float("inf")
-        t_comm = self.bytes_per_hop / ici_bw
-        t_comp = self.flops_per_hop / peak_flops
-        return t_comp / t_comm if t_comm else float("inf")
-
 
 def comm_schedule_jigsaw_1d(tokens: int, m: int, d_local: int, p: int,
-                            dtype_bytes: int = 2, chunked: bool = True,
-                            impl: Optional[str] = None) -> CommSchedule:
+                            dtype_bytes: int = 2,
+                            impl: str = "ring_chunked") -> CommSchedule:
     """Hop-level schedule of the explicit 1-D Jigsaw ring.
 
-    All three schedules move the same (p-1)/p * tokens * m bytes per
-    device; they differ in what compute is still pending while each hop's
-    send is in flight:
+    Both schedules move the same (p-1)/p * tokens * m bytes per device;
+    they differ in what compute is still pending while each hop's send
+    is in flight:
 
       ring         : nothing (the single GEMM finished before hop 0),
       ring_chunked : one output-chunk GEMM (2 * tokens * d_local * m/p
                      flops) -- *exposed to* XLA's scheduler, overlap
-                     best-effort,
-      ring_fused   : the same chunk GEMM plus the hop add (tokens * m/p
-                     VPU flops), executed *inside* the kernel while the
-                     RDMA flies -- overlap guaranteed by construction.
-
-    ``impl`` ("ring" | "ring_chunked" | "ring_fused") supersedes the
-    legacy ``chunked`` bool when given.
+                     best-effort.
     """
-    if impl is None:
-        impl = "ring_chunked" if chunked else "ring"
-    if impl not in ("ring", "ring_chunked", "ring_fused"):
+    if impl not in ("ring", "ring_chunked"):
         raise ValueError(f"comm_schedule_jigsaw_1d: unknown impl {impl!r}")
     hop_bytes = tokens * (m / p) * dtype_bytes
-    chunk_flops = 2.0 * tokens * d_local * (m / p)
-    flops = {"ring": 0.0, "ring_chunked": chunk_flops,
-             "ring_fused": chunk_flops + tokens * (m / p)}[impl]
+    flops = {"ring": 0.0,
+             "ring_chunked": 2.0 * tokens * d_local * (m / p)}[impl]
     return CommSchedule(
         scheme="jigsaw-1d-" + impl,
         hops=p - 1, bytes_per_hop=hop_bytes,

@@ -9,9 +9,8 @@ names the three dtypes that decide both:
   compute_dtype  dtype of every GEMM operand AND of every byte that rides
                  a collective (ring/Cannon ``ppermute`` chunks,
                  ``psum_scatter`` inputs).  bf16 halves per-hop ICI bytes
-                 relative to fp32 -- asserted on compiled HLO by
-                 ``benchmarks/comm_volume.py`` and the ``precision_bf16``
-                 dist scenario;
+                 relative to fp32 -- asserted on compiled HLO by the
+                 ``precision_bf16`` dist scenario;
   accum_dtype    dtype partial sums are ACCUMULATED in across ranks/chunks
                  (the ring's adds, Cannon's q-step accumulator).  The MXU
                  itself always accumulates fp32 inside the Pallas kernel

@@ -101,9 +101,10 @@ def _split_computations(hlo: str) -> Dict[str, List[str]]:
 
     Handles both the post-optimization header form
     ``%name (params) -> type {`` and the pre-optimization short form
-    ``name {`` (``compiler_ir(dialect='hlo')`` -- which the precision
-    benchmarks parse, because backend legalization may rewrite
-    collective dtypes: CPU widens bf16 collectives to f32)."""
+    ``name {`` (``compiler_ir(dialect='hlo')`` -- which the
+    ``precision_bf16`` dist scenario parses, because backend
+    legalization may rewrite collective dtypes: CPU widens bf16
+    collectives to f32)."""
     comps: Dict[str, List[str]] = {}
     cur = None
     for line in hlo.splitlines():

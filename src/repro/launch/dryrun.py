@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import ARCH_IDS, get_config
+from repro.core import jigsaw
 from repro.launch import analysis as A
 from repro.launch import shapes as SH
 from repro.launch.mesh import (make_production_mesh, make_production_mesh_2d)
@@ -201,9 +202,7 @@ def main():
     ap.add_argument("--all", action="store_true",
                     help="every (arch x shape), single+multi pod")
     ap.add_argument("--scheme", default=None, choices=["1d", "2d", "none"])
-    ap.add_argument("--impl", default=None,
-                    choices=["ring", "ring_chunked", "ring_fused", "rs",
-                             "gspmd", "allreduce"])
+    ap.add_argument("--impl", default=None, choices=jigsaw.Impl1D)
     ap.add_argument("--no-remat", action="store_true")
     ap.add_argument("--q-chunk", type=int, default=None,
                     help="chunked attention query-block size (beyond-paper)")

@@ -15,8 +15,8 @@ Replaces the monolithic ``train()`` loop: the engine owns
     restoring params/opt/step/rollout-schedule/pipeline-cursor --
     DESIGN.md §9).
 
-``launch/train.py``, the examples, and the measured benchmarks are thin
-callers of this class (DESIGN.md §7).
+``launch/train.py``, the examples, ``chip_smoke.py`` and the chip
+benchmark (``bench/``) are thin callers of this class (DESIGN.md §7).
 
 Typical use:
 
@@ -164,7 +164,7 @@ class TrainEngine:
 
         key = jax.random.PRNGKey(config.seed)
         # copy init_params: the step donates its buffers, and the caller
-        # may still hold them (e.g. fig56 evaluates the base model after)
+        # may still hold them (e.g. to evaluate the base model after)
         self.params = M.init(key, cfg) if init_params is None \
             else jax.tree.map(jnp.copy, init_params)
         if init_params is not None and config.precision:
@@ -682,19 +682,3 @@ class TrainEngine:
         self._prune_backlog = [
             p for p in man.extra.get("prune_backlog", [])
             if os.path.isdir(p)]
-
-    # -- benchmarking ----------------------------------------------------
-    def benchmark(self, steps: int = 10, warmup: int = 2) -> float:
-        """Steady-state seconds per training step (compile + warmup
-        excluded), through the engine's own pipeline -- used by the
-        measured scaling and pipeline-overlap benchmarks."""
-        horizons = np.ones(warmup + steps, np.int64)
-        with self._mesh_ctx():
-            it = self.pipeline.iterate(horizons)
-            for j, batch in enumerate(it):
-                if j == warmup:
-                    jax.block_until_ready(jax.tree.leaves(self.params)[0])
-                    t0 = time.time()
-                self.dispatch(batch, 1)
-            jax.block_until_ready(jax.tree.leaves(self.params)[0])
-        return (time.time() - t0) / steps

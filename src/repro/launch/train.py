@@ -36,6 +36,7 @@ import argparse
 import sys
 
 from repro.configs.registry import ARCH_IDS
+from repro.core import jigsaw
 from repro.launch import compile_cache, resilience
 from repro.launch.engine import EngineConfig, TrainEngine
 
@@ -56,9 +57,9 @@ def train(arch: str, *, steps: int = 100, batch: int = 8, seq_len: int = 128,
     """Back-compat functional entry point; returns (history, params).
 
     New callers should construct a :class:`TrainEngine` directly --
-    it exposes the same behavior plus eval/checkpoint/benchmark hooks.
-    ``config_override`` replaces the registry config (used by benchmarks
-    and examples that sweep custom model sizes)."""
+    it exposes the same behavior plus eval/checkpoint hooks.
+    ``config_override`` replaces the registry config (custom model
+    sizes)."""
     engine = TrainEngine(
         arch, reduced=reduced, mesh_model=mesh_model, mesh_data=mesh_data,
         scheme=scheme, impl=impl, kernel=kernel, init_params=init_params,
@@ -88,9 +89,7 @@ def main():
     ap.add_argument("--mesh-model", type=int, default=1)
     ap.add_argument("--mesh-data", type=int, default=1)
     ap.add_argument("--scheme", default=None, choices=["1d", "2d", "none"])
-    ap.add_argument("--impl", default=None,
-                    choices=["ring", "ring_chunked", "ring_fused", "rs",
-                             "gspmd", "allreduce"])
+    ap.add_argument("--impl", default=None, choices=jigsaw.Impl1D)
     ap.add_argument("--kernel", default=None, choices=["xla", "pallas"],
                     help="local GEMM engine (pallas = MXU-tiled fused "
                          "kernels; interpret mode on CPU)")

@@ -16,7 +16,7 @@ rollout.  One ``ForecastEngine`` owns:
     performs ZERO compiles: every function traces exactly once per
     bucket, counted by ``stats["compiles"]`` (incremented at trace time,
     so retraces are caught), and asserted by
-    ``benchmarks/serve_throughput.py``;
+    ``tests/test_serve.py``;
   * a ``MicrobatchScheduler`` (serve/scheduler.py) deciding, at every
     rollout-step boundary, which queued requests to admit into free
     slots (continuous batching), when to coalesce, grow, or -- in the

@@ -19,8 +19,8 @@ it.  Admitting there costs a single O(fields) dynamic-update on the
 donated state buffer; admitting mid-step would mean either recompiling
 (new batch shape) or re-running the partial step (wasted compute).
 Draining instead (classic static batching) makes every request wait for
-the slowest lead time in its batch -- the continuous-vs-drain benchmark
-(benchmarks/serve_throughput.py) measures exactly that gap.
+the slowest lead time in its batch -- ``tests/test_serve.py`` compares
+the two modes on the same requests.
 """
 from __future__ import annotations
 

@@ -2,15 +2,13 @@
 
 ``build_cost_model`` prices one step once per (ModelConfig, Jigsaw
 scheme, mesh shape): global FLOPs, the per-hop wire bytes of the
-explicit ring schedule (``core.jigsaw.comm_schedule_jigsaw_1d`` -- the
-same schedule the fused kernel enforces), and the compute and
-collective roofline terms they imply.  These are counts, not
+explicit ring schedule (``core.jigsaw.comm_schedule_jigsaw_1d``), and
+the compute and collective roofline terms they imply.  These are counts, not
 measurements: the engine stamps them into the run's meta header
 (``as_meta``) and no step record is derived from them.  The FLOPs side
-reuses ``launch/analysis.py``'s exact matmul-dims model; the roofline
-terms are the same formulas as ``benchmarks/fig7_roofline.py``
-(``fig7_point`` below reproduces that benchmark's rows bit-for-bit,
-pinned by tests/test_telemetry.py).
+reuses ``launch/analysis.py``'s exact matmul-dims model; ``fig7_point``
+below holds the paper's Fig. 7 roofline terms (its wm-1b rows are pinned
+by tests/test_telemetry.py).
 
 ``hlo_collective_bytes`` cross-checks the analytic wire model against a
 compiled step's actual HLO collectives (``launch/analysis.py`` parse).
@@ -150,8 +148,7 @@ def build_cost_model(cfg, *, n_model: int = 1, n_data: int = 1,
             sched = comm_schedule_jigsaw_1d(
                 tokens, m, cfg.d_model // p or 1, p,
                 dtype_bytes=wire,
-                impl=impl if impl in ("ring", "ring_chunked",
-                                      "ring_fused") else "ring")
+                impl=impl if impl == "ring_chunked" else "ring")
             comm = 3.0 * (comm_volume_jigsaw_1d(tokens, m, p,
                                                 dtype_bytes=wire)
                           .bytes_per_device * 2 * cfg.n_layers)
@@ -171,13 +168,12 @@ def build_cost_model(cfg, *, n_model: int = 1, n_data: int = 1,
 
 def fig7_point(cfg, way: int, impl: Optional[str] = None
                ) -> Dict[str, float]:
-    """One row of the Fig. 7 roofline, exactly as
-    ``benchmarks/fig7_roofline.py`` computes it (same formulas, same
-    constants) -- the pinned reference for the roofline terms.
+    """One row of the paper's Fig. 7 roofline -- the pinned reference
+    for the roofline terms (tests/test_telemetry.py).
 
     Returns t_step_s / tflops_per_dev / peak_frac / regime for a mixer
     config at jigsaw width ``way`` (1, 2 = 1-D ring, 4 = 2-D Cannon);
-    ``impl`` in ("ring_chunked", "ring_fused") applies the overlap
+    ``impl="ring_chunked"`` applies the overlap
     schedule ``t_comp/p + max(t_comp (p-1)/p, t_coll)``."""
     flops = 3 * sum(A.flops_forward(cfg, 1, 0).values())
     t_tokens = _tokens_per_sample(cfg)
@@ -193,7 +189,7 @@ def fig7_point(cfg, way: int, impl: Optional[str] = None
         v = 3 * (comm_volume_jigsaw_2d(t_tokens, cfg.wm_d_ch, 2)
                  .bytes_per_device * 2 * cfg.n_layers)
         t_coll, p_ring = v / A.ICI_BW, 2
-    if impl in ("ring_chunked", "ring_fused") and p_ring > 1:
+    if impl == "ring_chunked" and p_ring > 1:
         t_cc = t_comp / p_ring + max(t_comp * (p_ring - 1) / p_ring,
                                      t_coll)
     else:

@@ -10,7 +10,9 @@ Distributed half (pseudo-mesh of 16 host-emulated devices, subprocess):
 GEMM; under fp32 XLA GEMMs within the CPU dot's reduction-order
 tolerance) and == ``rs`` within f32 reduction-order tolerance, with AD
 through the chunked ring -- see
-tests/dist_scenarios.py::scenario_ring_chunked_parity.
+tests/dist_scenarios.py::scenario_ring_chunked_parity; and the 2-D
+transposed Cannon on the Pallas step kernel == its dot_general lowering
+(scenario_cannon_pallas_parity).
 """
 import os
 import subprocess
@@ -313,51 +315,24 @@ def test_ring_chunked_parity_pseudo_mesh():
 
 
 # ---------------------------------------------------------------------------
-# one-kernel ring: ring_fused == ring bit-identity + fused Cannon
+# transposed Cannon on the Pallas step kernel; the 1-D ring's schedules
 # ---------------------------------------------------------------------------
 
-def test_ring_fused_parity_pseudo_mesh():
-    """The acceptance criterion: ring_fused == ring bit-for-bit (fwd +
-    grads, fp32 and bf16, xla and pallas local GEMMs; fp32 xla GEMMs
-    within the CPU dot's reduction-order tolerance), the Pallas
-    transposed-Cannon parity, the VMEM guard, and a 2-step engine A/B --
-    see dist_scenarios.scenario_ring_fused_parity."""
+@pytest.mark.parametrize("compute", ["fp32", "bf16"])
+def test_cannon_pallas_parity_pseudo_mesh(compute):
+    """``jigsaw_linear_2d_t(kernel="pallas")`` (the Cannon loop on the
+    ``acc + w @ x`` step kernel) == ``kernel="xla"`` on a (1, 2, 2) mesh,
+    forward and grads -- see dist_scenarios.scenario_cannon_pallas_parity.
+    """
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
     env["JAX_PLATFORMS"] = "cpu"
     script = os.path.join(os.path.dirname(__file__), "dist_scenarios.py")
     res = subprocess.run(
-        [sys.executable, script, "ring_fused_parity"], env=env,
-        capture_output=True, text=True, timeout=600)
+        [sys.executable, script, f"cannon_pallas_parity:{compute}"],
+        env=env, capture_output=True, text=True, timeout=600)
     assert res.returncode == 0 and "ALL-OK" in res.stdout, (
         f"\nstdout:\n{res.stdout[-3000:]}\nstderr:\n{res.stderr[-3000:]}")
-
-
-def test_vmem_guards_count_their_choice():
-    """Each fused-kernel guard decision lands on the process tracer as
-    ``fused_ring.<guard>.<path>``, so a run can report which schedule
-    its kernels took."""
-    import warnings
-
-    from repro import telemetry
-    from repro.kernels import fused_ring
-
-    tr = telemetry.Tracer()
-    prev = telemetry.set_tracer(tr)
-    try:
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            fused_ring._select_path(4096, 4096, 65536, 8, jnp.float32,
-                                    jnp.float32, ("data", "model"), "model",
-                                    backend="tpu", budget=1 << 20)
-        fused_ring._select_path(64, 64, 128, 8, jnp.float32, jnp.float32,
-                                ("data", "model"), "model", backend="tpu")
-        fused_ring.cannon_path(1, 64, 64, 64, jnp.float32, None)
-    finally:
-        telemetry.set_tracer(prev)
-    assert tr.counters() == {"fused_ring.ring.fallback": 1.0,
-                             "fused_ring.ring.tpu": 1.0,
-                             "fused_ring.cannon.step": 1.0}
 
 
 def test_jigsaw_config_validation():
@@ -368,21 +343,23 @@ def test_jigsaw_config_validation():
         JigsawConfig(scheme="3d")
     with pytest.raises(ValueError, match="impl"):
         JigsawConfig(impl="ring_fuzed")
+    with pytest.raises(ValueError, match="impl"):
+        JigsawConfig(impl="ring_fused")
     with pytest.raises(ValueError, match="kernel"):
         JigsawConfig(kernel="triton")
     with pytest.warns(UserWarning, match="ignores"):
-        JigsawConfig(scheme="2d", impl="ring_fused")
+        JigsawConfig(scheme="2d", impl="ring_chunked")
     with warnings.catch_warnings():
         warnings.simplefilter("error")          # no spurious warnings
-        JigsawConfig(scheme="1d", impl="ring_fused", kernel="pallas")
+        JigsawConfig(scheme="1d", impl="ring_chunked", kernel="pallas")
         JigsawConfig(scheme="2d")               # default impl: fine
 
 
-def test_fused_ring_p1_smoke():
-    """p=1 runs the fused op without any ring (no RDMA primitives are
-    even traced); forward and grads equal the dense GEMM on both local
-    engines."""
-    from repro.kernels import fused_ring
+@pytest.mark.parametrize("kern", ["xla", "pallas"])
+def test_ring_chunked_p1_smoke(kern):
+    """A ring of one runs no hop: ``ring_matmul_chunked`` is the local
+    GEMM, forward and grads equal to the dense GEMM on both engines."""
+    from repro.core import jigsaw
 
     k1, k2 = jax.random.split(KEY)
     x = jax.random.normal(k1, (8, 24, 64))
@@ -391,25 +368,24 @@ def test_fused_ring_p1_smoke():
     def dense(xx, ww):
         return jnp.sum(jnp.einsum("btd,md->btm", xx, ww) ** 2)
 
-    for kern in ("xla", "pallas"):
-        def fused(xx, ww):
-            y = fused_ring.fused_ring_matmul(
-                xx, ww, axis_name="model", axis_size=1, kernel=kern)
-            return jnp.sum(y ** 2)
+    def chunked(xx, ww):
+        y = jigsaw.ring_matmul_chunked(xx, ww, axis_name="model",
+                                       axis_size=1, kernel=kern)
+        return jnp.sum(y ** 2)
 
-        v, g = jax.value_and_grad(fused, argnums=(0, 1))(x, w)
-        vr, gr = jax.value_and_grad(dense, argnums=(0, 1))(x, w)
-        np.testing.assert_allclose(float(v), float(vr), rtol=1e-4)
-        for a, b in zip(g, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-3, atol=1e-4)
+    v, g = jax.value_and_grad(chunked, argnums=(0, 1))(x, w)
+    vr, gr = jax.value_and_grad(dense, argnums=(0, 1))(x, w)
+    np.testing.assert_allclose(float(v), float(vr), rtol=1e-4)
+    for a, b in zip(g, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=1e-4)
 
 
 def test_cannon_t_step_parity():
     """The fused multiply-accumulate step kernel (acc + w @ x, f32 VMEM
     accumulation) matches the reference einsum for forward AND grads
     (custom VJP: dw/dx ride the same blocked machinery)."""
-    from repro.kernels import fused_ring
+    from repro.kernels import cannon
 
     k1, k2, k3 = jax.random.split(KEY, 3)
     w = jax.random.normal(k1, (20, 24)) * 0.1
@@ -417,12 +393,12 @@ def test_cannon_t_step_parity():
     acc = jax.random.normal(k3, (3, 20, 40))
 
     def f_pallas(ww, xx, aa):
-        return jnp.sum(fused_ring.cannon_t_step(ww, xx, aa) ** 2)
+        return jnp.sum(cannon.cannon_t_step(ww, xx, aa) ** 2)
 
     def f_ref(ww, xx, aa):
         return jnp.sum((aa + jnp.einsum("mt,btc->bmc", ww, xx)) ** 2)
 
-    y = fused_ring.cannon_t_step(w, x, acc)
+    y = cannon.cannon_t_step(w, x, acc)
     r = acc + jnp.einsum("mt,btc->bmc", w, x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(r), rtol=2e-5,
                                atol=2e-5)
@@ -432,51 +408,26 @@ def test_cannon_t_step_parity():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
     # None starts a fresh accumulator
-    y0 = fused_ring.cannon_t_step(w, x, None)
+    y0 = cannon.cannon_t_step(w, x, None)
     np.testing.assert_allclose(np.asarray(y0),
                                np.asarray(jnp.einsum("mt,btc->bmc", w, x)),
                                rtol=2e-5, atol=2e-5)
 
 
-def test_fused_ring_vmem_guard_units():
-    """The budget guard's arithmetic: footprint scales with the chunk
-    tiles, and the backend parameterization keeps CPU on the fallback."""
-    from repro.kernels import fused_ring
-
-    small = fused_ring.ring_footprint_bytes(64, 64, 512, 8, jnp.float32,
-                                            jnp.float32)
-    big = fused_ring.ring_footprint_bytes(4096, 4096, 65536, 8,
-                                          jnp.float32, jnp.float32)
-    assert small < big
-    assert fused_ring.fits_vmem(64, 64, 512, 8, jnp.float32, jnp.float32)
-    assert not fused_ring.fits_vmem(4096, 4096, 65536, 8, jnp.float32,
-                                    jnp.float32)
-    # bf16 wire halves the ring-buffer bytes
-    bf = fused_ring.ring_footprint_bytes(64, 64, 512, 8, jnp.bfloat16,
-                                         jnp.float32)
-    assert bf < small
-
-
-def test_comm_schedule_fused_rows():
-    """ring_fused hides the hop add in-kernel: strictly more overlappable
-    flops per hop than ring_chunked at identical wire bytes."""
+def test_comm_schedule_rows():
+    """``ring_chunked`` exposes one chunk GEMM per hop where ``ring``
+    exposes none, at identical wire bytes; other impls have no row."""
     from repro.core import jigsaw
 
     ring = jigsaw.comm_schedule_jigsaw_1d(4096, 4096, 512, 8, impl="ring")
     chunked = jigsaw.comm_schedule_jigsaw_1d(4096, 4096, 512, 8,
                                              impl="ring_chunked")
-    fused = jigsaw.comm_schedule_jigsaw_1d(4096, 4096, 512, 8,
-                                           impl="ring_fused")
     assert ring.flops_per_hop == 0.0
-    assert fused.flops_per_hop > chunked.flops_per_hop > 0
-    assert fused.bytes_per_hop == chunked.bytes_per_hop == ring.bytes_per_hop
-    assert fused.bytes_per_device == chunked.bytes_per_device
-    assert fused.scheme == "jigsaw-1d-ring_fused"
-    r = fused.overlap_ratio(50e9, 197e12)
-    assert r >= chunked.overlap_ratio(50e9, 197e12)
-    # legacy bool still works
-    legacy = jigsaw.comm_schedule_jigsaw_1d(4096, 4096, 512, 8,
-                                            chunked=True)
-    assert legacy.scheme == "jigsaw-1d-ring_chunked"
-    with pytest.raises(ValueError, match="impl"):
-        jigsaw.comm_schedule_jigsaw_1d(4096, 4096, 512, 8, impl="rs")
+    assert chunked.flops_per_hop == 2.0 * 4096 * 512 * (4096 / 8) > 0
+    assert chunked.bytes_per_hop == ring.bytes_per_hop
+    assert chunked.bytes_per_device == ring.bytes_per_device
+    assert chunked.hops == ring.hops == 7
+    assert chunked.scheme == "jigsaw-1d-ring_chunked"
+    for impl in ("ring_fused", "rs"):
+        with pytest.raises(ValueError, match="impl"):
+            jigsaw.comm_schedule_jigsaw_1d(4096, 4096, 512, 8, impl=impl)

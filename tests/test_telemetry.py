@@ -412,9 +412,8 @@ WM = "weathermixer-1b"
 
 
 def test_fig7_point_pinned():
-    """``fig7_point`` must reproduce benchmarks/fig7_roofline.py's
-    wm-1b rows bit-for-bit; these constants are PINNED -- if they move,
-    the roofline model changed and EXPERIMENTS.md is stale."""
+    """``fig7_point``'s wm-1b rows of the Fig. 7 roofline; these
+    constants are PINNED -- if they move, the roofline model changed."""
     cfg = get_config(WM)
     p1 = telemetry.fig7_point(cfg, 1)
     assert p1["peak_frac"] == pytest.approx(1.0)

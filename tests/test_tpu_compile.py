@@ -23,7 +23,9 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro.configs.registry import get_config
-from repro.kernels import fused_ring, ops
+from repro.core import jigsaw
+from repro.core.sharding import RULES_1D, RULES_2D
+from repro.kernels import cannon, ops
 from repro.models import weathermixer as WM
 
 WM1B = get_config("weathermixer-1b")
@@ -143,62 +145,61 @@ def test_cannon_t_step_kernel(one_chip):
     m_l, t_l, c_l = WM1B.wm_d_tok // 2, TOKENS // 2, WM1B.d_model // 2
 
     def fn(w, x, a):
-        return fused_ring._wx_raw(w, x, a, jnp.float32, interpret=False)
+        return cannon._wx_raw(w, x, a, jnp.float32, interpret=False)
 
     _compiles_as_tpu_kernel(fn, _sds((m_l, t_l), one_chip),
                    _sds((1, t_l, c_l), one_chip),
                    _sds((1, m_l, c_l), one_chip, jnp.float32))
 
 
-def test_fused_cannon_2x2(topo):
-    """The fused q-hop Cannon kernel (rotates as in-kernel remote copies)
-    on a 2x2 mesh of the described chips, at blocks under its VMEM
-    budget."""
+def _loss(y):
+    return jnp.sum(y.astype(jnp.float32) ** 2)
+
+
+@pytest.fixture
+def kernels_compiled(monkeypatch):
+    """The Jigsaw layers leave ``interpret`` to the kernels, which run
+    interpreted unless the default backend is a TPU; the described chips
+    are not the default backend here, so the trace is told it is one."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def test_cannon_2x2_token_mix(topo, kernels_compiled):
+    """wm-1b's first token GEMM under 2-D Jigsaw on a (1, 2, 2) mesh of
+    the described chips, ``kernel="pallas"``: the Cannon loop on the
+    step kernel, forward and gradients, x [1, T, d_emb] and
+    w [d_tok, T]."""
     d = topo.devices
-    mesh = Mesh([[d[0], d[1]], [d[2], d[3]]], ("mdom", "mtp"))
-    ll, m_l, t_l, c_l = 1, 256, 512, 512
-    assert fused_ring.cannon_footprint_bytes(
-        ll, m_l, t_l, c_l, BF16) <= fused_ring.VMEM_BUDGET_BYTES
+    mesh = Mesh([[[d[0], d[1]], [d[2], d[3]]]], ("data", "mdom", "mtp"))
 
-    def local(w, x):
-        return fused_ring._cannon_fwd_tpu(
-            w, x[0], dom_axis="mdom", tp_axis="mtp", q=2,
-            accum_dtype=jnp.float32, mesh_axes=("mdom", "mtp"))[None]
+    def fn(x, w):
+        return jax.value_and_grad(lambda xx, ww: _loss(
+            jigsaw.jigsaw_linear_2d_t(xx, ww, rules=RULES_2D, mesh=mesh,
+                                      kernel="pallas")),
+            argnums=(0, 1))(x, w)
 
-    fn = jax.shard_map(local, mesh=mesh,
-                       in_specs=(P("mdom", "mtp"),
-                                 P(None, None, "mdom", "mtp")),
-                       out_specs=P(None, None, "mdom", "mtp"),
-                       check_vma=False)
-    _compiles_as_tpu_kernel(
-        fn, _sds((2 * m_l, 2 * t_l), NamedSharding(mesh, P("mdom", "mtp"))),
-        _sds((1, ll, 2 * t_l, 2 * c_l),
-             NamedSharding(mesh, P(None, None, "mdom", "mtp"))))
+    text = _compiles_as_tpu_kernel(
+        fn, _sds((1, TOKENS, WM1B.d_model),
+                 NamedSharding(mesh, P(None, "mdom", "mtp"))),
+        _sds((WM1B.wm_d_tok, TOKENS), NamedSharding(mesh, P("mdom", "mtp"))))
+    assert "collective-permute" in text          # the skew and rotate hops
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_fused_ring_4(topo, direction):
-    """The one-kernel 1-D ring (in-kernel RDMA hops) on a 4-chip ring, at
-    a block under the fused path's VMEM guard."""
-    mesh = Mesh(list(topo.devices), ("model",))
-    p, rows, d_local, m = 4, 256, 1024, 4096
-    assert fused_ring.fits_vmem(rows, d_local, m, p, BF16, jnp.float32)
+def test_ring_chunked_4(topo, kernels_compiled, direction):
+    """wm-1b's channel GEMM under 1-D Jigsaw on a 4-chip ring of the
+    described chips, ``impl="ring_chunked"``, ``kernel="pallas"`` (the
+    1-D path ``configs/weathermixer_1b.py`` selects): x [T, d_emb],
+    w [d_ch, d_emb]."""
+    mesh = Mesh([list(topo.devices)], ("data", "model"))
     xs = NamedSharding(mesh, P(None, "model"))
 
-    if direction == "fwd":
-        def local(x, w):
-            return fused_ring._ring_fwd_tpu(x, w, "model", p, jnp.float32,
-                                            ("model",))
-        args = (_sds((rows, p * d_local), xs), _sds((m, p * d_local), xs))
-        out_specs = P(None, "model")
-    else:
-        def local(x, w, dy):
-            return fused_ring._ring_bwd_tpu(x, w, dy, "model", p,
-                                            ("model",))
-        args = (_sds((rows, p * d_local), xs), _sds((m, p * d_local), xs),
-                _sds((rows, m), xs))
-        out_specs = (P(None, "model"), P(None, "model"))
-    fn = jax.shard_map(local, mesh=mesh,
-                       in_specs=tuple(P(None, "model") for _ in args),
-                       out_specs=out_specs, check_vma=False)
-    _compiles_as_tpu_kernel(fn, *args)
+    def linear(x, w):
+        return jigsaw.jigsaw_linear(x, w, rules=RULES_1D, mesh=mesh,
+                                    impl="ring_chunked", kernel="pallas")
+
+    fn = linear if direction == "fwd" else jax.value_and_grad(
+        lambda x, w: _loss(linear(x, w)), argnums=(0, 1))
+    text = _compiles_as_tpu_kernel(fn, _sds((TOKENS, WM1B.d_model), xs),
+                                   _sds((WM1B.wm_d_ch, WM1B.d_model), xs))
+    assert "collective-permute" in text          # the ring's hops
